@@ -14,12 +14,15 @@ all of them:
   existing ``XLA_FLAGS`` (other flags survive; stale spellings of the
   same flag are replaced). Raises if the backend already initialized
   with a conflicting topology, and no-ops when the env already matches.
-* :func:`simulate_mesh` — CI's entry point: stage ``n`` forced host
-  devices, initialize jax, and return a 1-D device mesh over them. An
-  8-device CPU mesh exercises the full shard_map exchange
+* :func:`simulate_mesh` — the ``--mesh N`` entry point: a 1-D device
+  mesh over the first ``n`` devices. On an accelerator host those are
+  the real chips; on the CPU it stages ``n`` forced host devices first.
+  An 8-device CPU mesh exercises the full shard_map exchange
   (all_to_all/all_gather/psum routing) on a laptop or CI runner; see
   tests/helpers.py ``run_on_simulated_mesh`` for the subprocess fixture
   that guarantees the early-import requirement.
+* :func:`use_compile_cache` — turn on JAX's persistent compilation
+  cache from an entry point's ``main`` (never at import).
 
 Keep this module light: importing it must not initialize (or require)
 jax — :func:`stage` is pure env-var bookkeeping until something asks
@@ -30,8 +33,13 @@ from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
 
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed path: the cache directory is part of every entry's key, so one
+# that moved between runs would never hit
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def jax_initialized() -> bool:
@@ -116,9 +124,30 @@ def stage(*, host_device_count: int | None = None,
         os.environ["JAX_ENABLE_X64"] = want
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no other directory is set here; otherwise the cache goes to
+    ``.jax_cache/`` at the checkout root. Every compiled program is kept
+    (no minimum compile time), so a second run of the same entry point
+    compiles nothing. Call from ``main``, never at import."""
+    import jax
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def simulate_mesh(n: int, axis_names: tuple[str, ...] = ("data",)):
-    """Stage ``n`` forced host devices, initialize jax, and return a
-    1-D ``Mesh`` over the first ``n`` devices (CI's simulated pod).
+    """A 1-D ``Mesh`` over the first ``n`` devices of the process.
+
+    Where an accelerator is present (a TPU host) those are its chips,
+    and the staged host device count is ignored by the accelerator
+    backend. On the CPU, ``n`` forced host devices are staged first
+    (CI's simulated pod). Raises when fewer than ``n`` devices exist.
 
     Must be the first jax-touching call of the process (the subprocess
     fixture in tests/helpers.py guarantees this for tests; the serving
@@ -129,9 +158,10 @@ def simulate_mesh(n: int, axis_names: tuple[str, ...] = ("data",)):
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(
-            f"simulate_mesh({n}): only {len(devs)} device(s) visible — "
-            f"the forced host device count was staged after jax "
-            f"initialized. Call simulate_mesh (or stage) before any "
+            f"simulate_mesh({n}): only {len(devs)} "
+            f"{devs[0].platform} device(s) visible. On the CPU the "
+            f"forced host device count was staged after jax "
+            f"initialized: call simulate_mesh (or stage) before any "
             f"jax.devices()/array op, or use "
             f"tests/helpers.py:run_on_simulated_mesh.")
     return jax.sharding.Mesh(np.asarray(devs[:n]), axis_names)

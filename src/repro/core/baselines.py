@@ -151,8 +151,7 @@ def multiset_subtract_mask(live_pts, live_ok, del_pts, del_ok=None):
     idx = jnp.arange(n + m, dtype=jnp.int32)
     newrun = jnp.concatenate([jnp.ones((1,), bool),
                               jnp.any(sp[1:] != sp[:-1], axis=-1)])
-    runstart = jax.lax.associative_scan(jnp.maximum,
-                                        jnp.where(newrun, idx, 0))
+    runstart = jax.lax.cummax(jnp.where(newrun, idx, 0))
     # deletes per run, broadcast to members via segmented sum
     is_del = (~sl) & so
     cdel = jnp.cumsum(is_del.astype(jnp.int32))

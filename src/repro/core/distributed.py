@@ -24,11 +24,10 @@ the multi-node analogue of the paper's shared-memory design:
     combines candidates; exact because shards partition the point set.
     Range-count is a local count + psum.
 
-Every collective program here is built by an ``lru_cache`` closure
-factory returning ``jax.jit(shard_map(local))`` — jit *around* the
-shard region (the one legal nesting direction on jax 0.4.x; a jit
-*inside* would hit the nested-jit miscompile, which is why every local
-call is an unjitted ``*_impl`` spelling). The serving hot path
+Every collective program here — updates and queries — is built by an
+``lru_cache`` closure factory returning ``jax.jit(shard_map(local))``:
+jit *around* the shard region, and every local call an unjitted
+``*_impl`` spelling (the ``jit-in-shard-map`` contract). The serving hot path
 (``SpatialServer`` over a :class:`repro.core.index.DistributedIndex`)
 therefore dispatches updates and coalesced queries with zero retraces
 after warmup — the query closures bump ``repro.core.engine``'s trace
@@ -56,11 +55,6 @@ from . import porth
 from . import queries as Q
 from . import spac
 from .leafstore import BIG, group_occurrence
-
-try:                      # jax >= 0.6 spells it jax.shard_map
-    shard_map = jax.shard_map
-except AttributeError:    # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 P = jax.sharding.PartitionSpec
 
@@ -164,12 +158,8 @@ def _route_exchange(pts, mask, splitters, axis, n_shards: int, cap: int,
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax < 0.6 spells the replication check check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def _pad_rows(pts, mask, n_shards: int):
@@ -257,24 +247,44 @@ def _update_closure(mesh, axis: str, n_shards: int, cap: int, kind: str,
         out_specs=(P(axis), P())))
 
 
-# Query closures deliberately do NOT use shard_map. Queries need no
-# routing — every shard answers over its whole subtree and a global
-# merge combines candidates — so they can be spelled as a plain jitted
-# vmap over the stacked shard axis. GSPMD then partitions each vmap
-# lane onto its device (the tree leaves are sharded on that axis) and
-# inserts the gather for the merge itself. That keeps queries on the
-# standard SPMD compile path: under manual partitioning
-# (jit-around-shard_map, check_rep=False) the frontier traversal's
-# vmapped while_loop with a loop-carried exit bound miscompiles on
-# shards != 0 on jax 0.4.x — empirically isolated; update closures
-# avoid it because their while_loops are unbatched — and the vmap
-# spelling sidesteps the whole class while staying cached + exact.
+# Query closures are shard_map programs like the updates: each shard
+# answers over its whole subtree with the unjitted local spelling, and
+# the merge exchanges only query-sized results — an all_gather of the
+# per-shard top-k (kNN) or a psum of per-shard counts (range). A
+# GSPMD-partitioned vmap over the stacked shard axis cannot carry the
+# Pallas routes: Mosaic kernels are never partitioned automatically.
 
 @functools.lru_cache(maxsize=None)
-def _knn_closure(k: int, impl: str, kernel: str, chunk: int):
+def _knn_closure(mesh, axis: str, k: int, impl: str, kernel: str,
+                 chunk: int):
     obs.count("dist.plan_miss")
     from ..kernels.frontier import ops as frontier_ops
     from ..kernels.knn import ops as knn_ops
+
+    def local(tree, q):
+        view = _unstack(tree).view()
+        if impl == "frontier":
+            d2, ids = Q.knn_impl(view, q, k, chunk)
+        elif impl == "pallas-frontier":
+            d2, ids = frontier_ops.knn_frontier_impl(
+                view.pts, view.valid, view.active, view.bbox_lo,
+                view.bbox_hi, q, k=k, impl=kernel)
+        else:
+            flat_pts, flat_ok = Q.flatten_view(view)
+            d2, ids = knn_ops.knn_bruteforce_impl(
+                q, flat_pts, flat_ok, k=k, impl=kernel)
+        pts = Q.gather_points(view, ids)
+        d2 = jnp.where(ids >= 0, d2, BIG)
+        # (Q, S*k) candidates, shard-major: top_k's lowest-index tie
+        # break then prefers the lower shard
+        cat_d2 = jax.lax.all_gather(d2, axis, axis=1, tiled=True)
+        cat_pts = jax.lax.all_gather(pts, axis, axis=1, tiled=True)
+        neg, sel = jax.lax.top_k(-cat_d2, k)
+        best = jnp.take_along_axis(cat_pts, sel[..., None], axis=1)
+        return -neg, best, (-neg) < BIG
+
+    smapped = _smap(local, mesh, in_specs=(P(axis), P()),
+                    out_specs=(P(), P(), P()))
 
     def run(tree, q):
         # trace-time counter: same contract as the engine's local query
@@ -282,44 +292,28 @@ def _knn_closure(k: int, impl: str, kernel: str, chunk: int):
         # the distributed merge too
         _engine._STATS["traces"] += 1
         obs.count("engine.trace")
-
-        def one(shard_tree):
-            view = shard_tree.view()
-            if impl == "frontier":
-                d2, ids = Q.knn_impl(view, q, k, chunk)
-            elif impl == "pallas-frontier":
-                d2, ids = frontier_ops.knn_frontier_impl(
-                    view.pts, view.valid, view.active, view.bbox_lo,
-                    view.bbox_hi, q, k=k, impl=kernel)
-            else:
-                flat_pts, flat_ok = Q.flatten_view(view)
-                d2, ids = knn_ops.knn_bruteforce_impl(
-                    q, flat_pts, flat_ok, k=k, impl=kernel)
-            pts = Q.gather_points(view, ids)
-            return jnp.where(ids >= 0, d2, BIG), pts
-
-        all_d2, all_pts = jax.vmap(one)(tree)     # (S, Q, k), (S, Q, k, d)
-        S, qn, _ = all_d2.shape
-        cat_d2 = all_d2.transpose(1, 0, 2).reshape(qn, S * k)
-        cat_pts = all_pts.transpose(1, 0, 2, 3).reshape(qn, S * k, -1)
-        neg, sel = jax.lax.top_k(-cat_d2, k)
-        best = jnp.take_along_axis(cat_pts, sel[..., None], axis=1)
-        return -neg, best, (-neg) < BIG
+        return smapped(tree, q)
 
     return jax.jit(run)
 
 
 @functools.lru_cache(maxsize=None)
-def _range_count_closure(max_rows: int):
+def _range_count_closure(mesh, axis: str, max_rows: int):
     obs.count("dist.plan_miss")
+
+    def local(tree, lo, hi):
+        cnt, trunc = Q.range_count_impl(_unstack(tree).view(), lo, hi,
+                                        max_rows)
+        return (jax.lax.psum(cnt, axis),
+                jax.lax.psum(trunc.astype(jnp.int32), axis) > 0)
+
+    smapped = _smap(local, mesh, in_specs=(P(axis), P(), P()),
+                    out_specs=(P(), P()))
 
     def run(tree, lo, hi):
         _engine._STATS["traces"] += 1
         obs.count("engine.trace")
-        cnt, trunc = jax.vmap(
-            lambda st: Q.range_count_impl(st.view(), lo, hi, max_rows)
-        )(tree)
-        return jnp.sum(cnt, axis=0), jnp.any(trunc, axis=0)
+        return smapped(tree, lo, hi)
 
     return jax.jit(run)
 
@@ -396,19 +390,14 @@ def knn(index: DistIndex, qpts, k: int, mesh, chunk: int = 8,
     ``impl="frontier"`` runs the chunked frontier traversal per shard;
     ``impl="pallas-frontier"`` the fused frontier kernel;
     ``impl="flat"`` the brute-force scan (``kernel`` picks the kernel
-    flavor: auto/pallas/pallas-interpret/ref). ``mesh`` is accepted for
-    API symmetry with the update path; the query program is
-    shard-agnostic (vmap over the stacked axis — see the closure
-    comment) so the arrays' own sharding drives the partitioning."""
-    del mesh
-    fn = _knn_closure(int(k), impl, kernel, int(chunk))
+    flavor: auto/pallas/pallas-interpret/ref)."""
+    fn = _knn_closure(mesh, index.axis, int(k), impl, kernel, int(chunk))
     return fn(index.tree, qpts)
 
 
 def range_count(index: DistIndex, lo, hi, mesh, max_rows: int = 128):
     """Exact distributed range-count: per-shard count + global sum."""
-    del mesh
-    fn = _range_count_closure(int(max_rows))
+    fn = _range_count_closure(mesh, index.axis, int(max_rows))
     return fn(index.tree, lo, hi)
 
 

@@ -24,10 +24,10 @@ silently violated. The :class:`QueryEngine` owns those knobs instead:
   count ``R*C`` fits a flat-scan budget (small indexes, post-compact
   trees) and to the fused frontier kernel
   (:mod:`repro.kernels.frontier`) otherwise — pruned traversal with
-  the running top-k on-chip, compensated (centered) MXU distances for
-  selection, and a direct ``|q - p|^2`` rescore of the k hits, so the
-  returned distances match the chunked traversal at any coordinate
-  magnitude. Forced
+  the running top-k on-chip and the direct ``|q - p|^2`` distances the
+  chunked traversal computes, so both return the same distances at any
+  coordinate magnitude. On a TPU both kernels run compiled; elsewhere
+  ``auto`` runs their jnp mirrors. Forced
   spellings: ``"frontier"`` (chunked host-orchestrated traversal,
   ``chunk`` auto-picked from R), ``"pallas-frontier"``,
   ``"pallas-frontier-interpret"``, ``"flat"`` (brute force, kernel
@@ -62,8 +62,8 @@ from .leafstore import BIG
 DEFAULT_MAX_ROWS = 128
 DEFAULT_CAP = 512
 # slot count (R*C) below which a flat brute-force scan beats the
-# frontier traversal's sort + while_loop (the whole index fits a few
-# MXU tiles); above it the bbox pruning wins
+# frontier traversal's sort + while_loop (the whole index is a few
+# kernel tiles); above it the bbox pruning wins. Set from CPU runs
 DEFAULT_FLAT_BUDGET = 1 << 15
 
 KNN_IMPLS = ("auto", "frontier", "pallas-frontier",

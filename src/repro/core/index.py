@@ -238,7 +238,12 @@ for _name, _curve, _sort in (("spac-h", "hilbert", False),
         compact=spac.compact, cap_slack=4,
         build_params=("curve", "bits", "coord_bits"),
         insert_params=("max_overflow_rows", "sort_rows"),
-        defaults=dict(curve=_curve, bits=16, coord_bits=30,
+        # coord_bits: the default integer domain [0, 2^20) (as
+        # DEFAULT_ROOT_HI). Quantizing that domain from 30 bits would keep
+        # 6 bits per dim, 4096 codes: at millions of points each code
+        # fills a run of rows, all inserts of a code land in the run's
+        # last row, and a 1% batch overflows thousands of rows at once
+        defaults=dict(curve=_curve, bits=16, coord_bits=20,
                       max_overflow_rows=64, sort_rows=_sort),
         curve=_curve))
 

@@ -80,8 +80,10 @@ def group_occurrence(group_ids):
     idx = jnp.arange(n, dtype=jnp.int32)
     change = jnp.concatenate(
         [jnp.ones((1,), bool), group_ids[1:] != group_ids[:-1]])
-    run_first = jax.lax.associative_scan(jnp.maximum,
-                                         jnp.where(change, idx, 0))
+    # cummax, not associative_scan: on the TPU it lowers to one
+    # reduce-window, where the scan's log-depth slicing takes minutes to
+    # compile at millions of points
+    run_first = jax.lax.cummax(jnp.where(change, idx, 0))
     return idx - run_first
 
 
@@ -134,7 +136,7 @@ def batch_rank_among_equals(sorted_pts, row_of, window: int, mask=None):
 
 def slot_rank_among_equals(pts_rows, valid_rows):
     """For every slot: number of earlier valid slots in the same row holding
-    an identical point. pts_rows: (R, C, D) -> (R, C) int32."""
+    an identical point. pts_rows: (n, C, D) -> (n, C) int32."""
     eq = jnp.all(pts_rows[:, :, None, :] == pts_rows[:, None, :, :], axis=-1)
     C = pts_rows.shape[1]
     earlier = jnp.tril(jnp.ones((C, C), bool), k=-1)[None]
@@ -150,14 +152,14 @@ def ranked_delete(pts_rows, valid_rows, count, row_of, del_pts, del_mask,
     entries remove distinct copies (rank matching). Returns updated
     (valid_rows, count, matched_mask).
     """
-    R, C, _ = pts_rows.shape
+    R = pts_rows.shape[0]
     n = del_pts.shape[0]
     brank = batch_rank_among_equals(del_pts, row_of, window, del_mask)
-    srank = slot_rank_among_equals(pts_rows, valid_rows)   # (R, C)
-    # per batch point: candidate slots in its row
+    # per batch point: candidate slots in its row; slot ranks only for
+    # these gathered rows — O(n C^2) temp, not O(R C^2) for the tree
     rows_p = pts_rows[row_of]            # (n, C, D)
     rows_v = valid_rows[row_of]          # (n, C)
-    rows_r = srank[row_of]               # (n, C)
+    rows_r = slot_rank_among_equals(rows_p, rows_v)     # (n, C)
     eq = jnp.all(rows_p == del_pts[:, None, :], axis=-1)
     hit = eq & rows_v & (rows_r == brank[:, None]) & del_mask[:, None]
     matched = jnp.any(hit, axis=-1)
@@ -182,6 +184,22 @@ def compact_rows(valid_rows, *slot_arrays):
             idx, order.shape + arr.shape[2:]) if arr.ndim > 2 else order,
             axis=1))
     return tuple(out)
+
+
+def compact_touched(touched, max_rows: int, valid_rows, *slot_arrays):
+    """:func:`compact_rows` for the touched rows only (a delete batch of
+    m entries touches at most m rows, so ``max_rows = min(m, R)`` is
+    exact). Returns ``(dest, compacted)``: ``dest`` (max_rows,) row ids
+    to scatter back into with ``.at[dest].set(..., mode="drop")`` (``R``
+    for padding, which the scatter drops), and the compacted
+    (max_rows, C, ...) gathers of ``valid_rows`` and every slot array.
+    Temps are O(max_rows * C), not O(R * C)."""
+    R = valid_rows.shape[0]
+    rows, _ = take_k_where(touched, max_rows)
+    safe = jnp.maximum(rows, 0)
+    dest = jnp.where(rows >= 0, rows, R)
+    return dest, compact_rows(valid_rows[safe],
+                              *(a[safe] for a in slot_arrays))
 
 
 def take_k_where(mask, k: int):

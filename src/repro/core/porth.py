@@ -37,9 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .leafstore import (chunk_rows_from_sorted, compact_rows, ranked_delete,
-                        row_bbox_from_slots, scatter_to_rows, segment_bbox,
-                        take_k_where)
+from .leafstore import (chunk_rows_from_sorted, compact_touched,
+                        ranked_delete, row_bbox_from_slots, scatter_to_rows,
+                        segment_bbox, take_k_where)
 from .queries import LeafView
 
 KEY_MAX = np.uint32(0xFFFFFFFF)  # numpy: keep import device-free
@@ -140,7 +140,7 @@ def _group_stats(sorted_key, ok):
     gid = jnp.cumsum(change.astype(jnp.int32)) - 1
     cnt_per_gid = jnp.zeros(n, jnp.int32).at[gid].add(ok.astype(jnp.int32))
     cnt = cnt_per_gid[gid]
-    gstart = jax.lax.associative_scan(jnp.maximum, jnp.where(change, idx, 0))
+    gstart = jax.lax.cummax(jnp.where(change, idx, 0))
     return gid, cnt, idx - gstart
 
 
@@ -562,14 +562,16 @@ def delete_impl(tree: POrthTree, del_pts, del_mask=None) -> POrthTree:
     _, valid_rows, count, _, touched = jax.lax.while_loop(
         cond, body, (jnp.int32(0), tree.valid, tree.count, contained,
                      jnp.zeros(R, bool)))
-    cvalid, cpts = compact_rows(valid_rows, tree.pts)
-    valid_rows = jnp.where(touched[:, None], cvalid, valid_rows)
-    pts_rows = jnp.where(touched[:, None, None], cpts, tree.pts)
-
+    # compact and refresh only the touched rows (at most one per entry)
     active = tree.active & (count > 0)
-    lo, hi = row_bbox_from_slots(pts_rows, valid_rows & active[:, None])
-    bbox_lo = jnp.where(touched[:, None], lo, tree.bbox_lo)
-    bbox_hi = jnp.where(touched[:, None], hi, tree.bbox_hi)
+    dest, (cvalid, cpts) = compact_touched(touched, min(m, R), valid_rows,
+                                           tree.pts)
+    lo, hi = row_bbox_from_slots(
+        cpts, cvalid & active[jnp.minimum(dest, R - 1)][:, None])
+    valid_rows = valid_rows.at[dest].set(cvalid, mode="drop")
+    pts_rows = tree.pts.at[dest].set(cpts, mode="drop")
+    bbox_lo = tree.bbox_lo.at[dest].set(lo, mode="drop")
+    bbox_hi = tree.bbox_hi.at[dest].set(hi, mode="drop")
     arrays = dict(pts=pts_rows, valid=valid_rows, count=count, active=active,
                   bbox_lo=bbox_lo, bbox_hi=bbox_hi, cell_lo=tree.cell_lo,
                   cell_hi=tree.cell_hi,

@@ -34,9 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import sfc
-from .leafstore import (append_unsorted, chunk_rows_from_sorted, compact_rows,
-                        group_occurrence, ranked_delete, row_bbox_from_slots,
-                        scatter_to_rows, segment_bbox, take_k_where)
+from .leafstore import (append_unsorted, chunk_rows_from_sorted,
+                        compact_touched, group_occurrence, ranked_delete,
+                        row_bbox_from_slots, scatter_to_rows, segment_bbox,
+                        take_k_where)
 from .queries import LeafView
 
 CODE_MAX = np.uint32(0xFFFFFFFF)  # numpy: keep import device-free
@@ -106,9 +107,18 @@ def _dir_mincodes(tree: SpacTree):
     return mc[tree.order]
 
 
-def _rebuild_order(active, min_code):
+def _rebuild_order(active, min_code, codes, valid):
+    """Directory order: active rows by (min_code, max code), then row id.
+
+    Rows that share a min_code v hold only v, except the last of the run
+    in code order, whose max is larger. Row ids alone do not give that
+    order once a split has moved a run's leading rows into higher free
+    row ids; sorting on the max puts that row last again, where routing
+    (the last row with min_code <= code) expects it."""
     key = jnp.where(active, min_code, CODE_MAX)
-    order = jnp.argsort(key).astype(jnp.int32)
+    max_code = jnp.max(jnp.where(valid, codes, 0), axis=1)
+    rows = jnp.arange(key.shape[0], dtype=jnp.int32)
+    _, _, order = jax.lax.sort((key, max_code, rows), num_keys=2)
     return order, jnp.sum(active, dtype=jnp.int32)
 
 
@@ -164,7 +174,8 @@ def build_impl(points, mask=None, *, phi: int = 32, curve: str = "hilbert",
     bbox_lo, bbox_hi = segment_bbox(s_pts, row, s_ok, R)
     min_code = jnp.full(R, CODE_MAX, jnp.uint32).at[
         jnp.where(s_ok, row, R)].min(s_codes, mode="drop")
-    order, num_rows = _rebuild_order(active, min_code)
+    order, num_rows = _rebuild_order(active, min_code, codes_rows,
+                                     valid_rows)
     return SpacTree(pts=pts_rows, codes=codes_rows, valid=valid_rows,
                     count=count, active=active, bbox_lo=bbox_lo,
                     bbox_hi=bbox_hi, min_code=min_code,
@@ -316,7 +327,8 @@ def insert_impl(tree: SpacTree, new_pts, new_mask=None, *,
             pts_rows, order_c[..., None].repeat(dim, -1), axis=1)
         unsorted = jnp.zeros_like(unsorted)
 
-    order, num_rows = _rebuild_order(active, min_code)
+    order, num_rows = _rebuild_order(active, min_code, codes_rows,
+                                     valid_rows)
     new_tree = dataclasses.replace(
         tree, pts=pts_rows, codes=codes_rows, valid=valid_rows, count=count,
         active=active, bbox_lo=bbox_lo, bbox_hi=bbox_hi, min_code=min_code,
@@ -386,20 +398,22 @@ def delete_impl(tree: SpacTree, del_pts, del_mask=None) -> SpacTree:
     _, valid_rows, count, _, touched = jax.lax.while_loop(
         cond, body, (jnp.int32(0), tree.valid, tree.count, s_ok,
                      jnp.zeros(R, bool)))
-    # intra-row stable compaction keeps `count == leading valid slots`
-    cvalid, cpts, ccodes = compact_rows(valid_rows, tree.pts, tree.codes)
-    valid_rows = jnp.where(touched[:, None], cvalid, valid_rows)
-    pts_rows = jnp.where(touched[:, None, None], cpts, tree.pts)
-    codes_rows = jnp.where(touched[:, None], ccodes, tree.codes)
-
+    # intra-row stable compaction keeps `count == leading valid slots`;
+    # only touched rows change, so only they are gathered and refreshed
     active = tree.active & (count > 0)
-    lo, hi = row_bbox_from_slots(pts_rows, valid_rows & active[:, None])
-    bbox_lo = jnp.where(touched[:, None], lo, tree.bbox_lo)
-    bbox_hi = jnp.where(touched[:, None], hi, tree.bbox_hi)
-    mc = jnp.min(jnp.where(valid_rows & active[:, None], codes_rows,
-                           CODE_MAX), axis=1)
-    min_code = jnp.where(touched, mc, tree.min_code)
-    order, num_rows = _rebuild_order(active, min_code)
+    dest, (cvalid, cpts, ccodes) = compact_touched(
+        touched, min(m, R), valid_rows, tree.pts, tree.codes)
+    live = cvalid & active[jnp.minimum(dest, R - 1)][:, None]
+    lo, hi = row_bbox_from_slots(cpts, live)
+    mc = jnp.min(jnp.where(live, ccodes, CODE_MAX), axis=1)
+    valid_rows = valid_rows.at[dest].set(cvalid, mode="drop")
+    pts_rows = tree.pts.at[dest].set(cpts, mode="drop")
+    codes_rows = tree.codes.at[dest].set(ccodes, mode="drop")
+    bbox_lo = tree.bbox_lo.at[dest].set(lo, mode="drop")
+    bbox_hi = tree.bbox_hi.at[dest].set(hi, mode="drop")
+    min_code = tree.min_code.at[dest].set(mc, mode="drop")
+    order, num_rows = _rebuild_order(active, min_code, codes_rows,
+                                     valid_rows)
     return dataclasses.replace(
         tree, pts=pts_rows, codes=codes_rows, valid=valid_rows, count=count,
         active=active, bbox_lo=bbox_lo, bbox_hi=bbox_hi, min_code=min_code,
@@ -429,7 +443,8 @@ def grow(tree: SpacTree, capacity_rows: int) -> SpacTree:
         bbox_hi=pad(tree.bbox_hi, -big),
         min_code=pad(tree.min_code, CODE_MAX),
         unsorted=pad(tree.unsorted, False))
-    order, num_rows = _rebuild_order(arrays["active"], arrays["min_code"])
+    order, num_rows = _rebuild_order(arrays["active"], arrays["min_code"],
+                                     arrays["codes"], arrays["valid"])
     return dataclasses.replace(tree, **arrays, order=order,
                                num_rows=num_rows)
 

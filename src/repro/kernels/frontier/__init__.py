@@ -1,25 +1,26 @@
-"""Fused frontier-kNN kernel: on-chip traversal with compensated distances.
+"""Fused frontier-kNN kernel: on-chip traversal with exact distances.
 
 The chunked frontier in ``core/queries.py`` pays a per-query ``argsort``
-over all R rows plus gather-heavy ``while_loop`` chunk bodies that never
-touch the MXU.  This package fuses that traversal into one launch:
+over all R rows plus gather-heavy ``while_loop`` chunk bodies.  This
+package fuses that traversal into a kernel:
 
-* rows are packed into contiguous *groups* of ``block_r`` rows once
+* rows are packed into contiguous *groups* of ``block_r`` rows
   (``prep.py``), so the traversal order is a per-query-*block* argsort
   over G = ceil(R / block_r) group lower bounds — not R rows per query;
-* candidate groups are scored with the centered MXU identity
-  ``|q-c|^2 - 2(q-c)(p-c) + |p-c|^2`` (``c`` = group bbox midpoint), which
-  is bit-exact against the frontier's ``(q-p)^2`` whenever the *centered*
-  intermediates stay in the f32-exact window — the spatial-locality regime
-  the index's SFC leaf ordering guarantees; the selected k hits are then
-  rescored with the direct ``(q-p)^2`` (``ops.py``), so the *returned*
-  distances match the chunked route bit-for-bit even when a tile's
-  spread dwarfs the neighbor distances and the identity cancels;
-* the running top-k merge and the frontier cursor live in VMEM scratch,
-  and the bbox-lower-bound early exit is a per-block ``pl.when`` skip, so
-  converged query blocks stop reading HBM (``kernel.py``);
+  points are stored lane-dense, ``(D + 1, G * P)`` (coordinates, then
+  validity);
+* a tile's distances are the direct ``sum_d (q_d - p_d)^2`` on the VPU,
+  exact whenever the squared differences are f32-exact, whatever the
+  coordinates' magnitude (a D <= 3 contraction would use a sliver of
+  the MXU anyway); the k hits are then re-scored with the chunked
+  frontier's own expression (``prep.rescore``), so every route returns
+  the same distances;
+* the running top-k merge is k rounds of min-extraction (lowest id on
+  ties) in VMEM scratch, and the bbox-lower-bound early exit is a
+  per-block ``pl.when`` skip plus a ``while_loop`` over launches that
+  stops at the first chunk no block can reach (``kernel.py``);
 * ``ref.py`` is a pure-jnp ``while_loop`` mirror sharing the same prep
-  and the same distance expression graph — bit-identical to the kernel in
+  and the same tile expressions — bit-identical to the kernel in
   interpret mode and the fast CPU spelling behind ``impl="auto"``.
 
 Routing lives in ``ops.py`` (canonical spellings: ``auto`` / ``pallas`` /

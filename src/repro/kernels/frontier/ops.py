@@ -17,10 +17,9 @@ constraints"); ``knn_frontier`` is the jitted module-level alias.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.frontier import kernel, ref, tuning
-from repro.kernels.frontier.prep import BIG, prepare
+from repro.kernels.frontier.prep import prepare, rescore
 
 FRONTIER_IMPLS = ("auto", "pallas", "pallas-interpret", "ref")
 
@@ -44,13 +43,10 @@ def knn_frontier_impl(pts, valid, active, bbox_lo, bbox_hi, queries, *,
     """Fused frontier kNN over leaf-view arrays; returns (d2, ids).
 
     ``ids`` are flat ``row * C + col`` candidate ids (-1 past the end),
-    matching the chunked frontier in ``core/queries.py``. The centered
-    MXU identity *selects* the candidates on-chip; the returned
-    distances are then rescored with the direct ``|q - p|^2`` the
-    chunked traversal uses, so scores stay well-conditioned even when
-    one tile spans a whole shard (tile-local spread >> neighbor
-    distances, where the expanded identity cancels catastrophically)
-    and are bit-identical to the chunked route for the same candidate.
+    matching the chunked frontier in ``core/queries.py``; each query's
+    hits come back sorted by ``(d2, id)``, re-scored with the direct
+    ``|q - p|^2`` expression the chunked traversal computes
+    (:func:`prep.rescore`).
     """
     impl = canonical_impl(impl)
     if impl == "auto":
@@ -64,13 +60,8 @@ def knn_frontier_impl(pts, valid, active, bbox_lo, bbox_hi, queries, *,
         d2, ids = kernel.knn_frontier_pallas(
             pr, k=k, interpret=(impl == "pallas-interpret"))
     q = queries.shape[0]
-    d2, ids = d2[:q][pr.inv], ids[:q][pr.inv]
-    flat = pts.astype(jnp.float32).reshape(-1, pts.shape[-1])
-    diff = flat[jnp.clip(ids, 0)] - \
-        queries.astype(jnp.float32)[:, None, :]
-    d2 = jnp.where(ids < 0, BIG, jnp.sum(diff * diff, axis=-1))
-    d2, ids = jax.lax.sort((d2, ids), dimension=-1, num_keys=2)
-    return d2, jnp.where(d2 >= BIG, -1, ids)
+    return rescore(pts.reshape(-1, pts.shape[-1]), queries,
+                   ids[:q][pr.inv])
 
 
 knn_frontier = jax.jit(

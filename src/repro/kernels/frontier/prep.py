@@ -1,14 +1,15 @@
 """Shared host-side prep for the fused frontier kernel and its jnp ref.
 
 Everything here is plain jnp (jit-safe, shard_map-safe) and is shared by
-both spellings so their inputs — group packing, centers, per-block
-traversal order — are bit-identical by construction.
+both spellings so their inputs — group packing, per-block traversal
+order — are bit-identical by construction.
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 BIG = 3.4e38  # python float: kernels close over it without a captured const
@@ -37,15 +38,40 @@ def morton_key(q: jnp.ndarray, bits: int = _MORTON_BITS) -> jnp.ndarray:
     return code
 
 
+def pack_points(pts, ok):
+    """Lane-dense ``(D + 1, N)`` f32 operand from ``(N, D)`` points and
+    ``(N,)`` validity: rows 0..D-1 are the coordinates, row D is 1.0 for
+    a live slot and 0.0 otherwise. With points on the lane axis a
+    kernel tile is ``(D + 1, P)``, which the TPU's (8, 128) tiling pads
+    at most to 8 rows — an ``(N, D)`` array would pad D to 128 lanes."""
+    return jnp.concatenate([pts.astype(jnp.float32).T,
+                            ok.astype(jnp.float32)[None, :]])
+
+
+def rescore(points, queries, ids):
+    """Re-score each query's hits as ``sum((p - q)^2, axis=-1)`` and
+    re-sort them by ``(d2, id)`` (-1 ids score ``BIG``).
+
+    A kernel tile sums ``D`` separate squares, which XLA may fuse into a
+    different rounding than the minor-axis reduction the chunked
+    frontier and the jnp oracle use; beyond 2^24 the two differ in the
+    last ulp. Re-scoring the k hits with the one shared expression makes
+    every route return identical distances. ``points`` is ``(N, D)``,
+    ``ids`` ``(Q, k)`` indices into it."""
+    p = points.astype(jnp.float32)[jnp.clip(ids, 0)]
+    diff = p - queries.astype(jnp.float32)[:, None, :]
+    d2 = jnp.where(ids < 0, BIG, jnp.sum(diff * diff, axis=-1))
+    d2, ids = jax.lax.sort((d2, ids), dimension=-1, num_keys=2)
+    return d2, jnp.where(d2 >= BIG, -1, ids)
+
+
 class FrontierPrep(NamedTuple):
     """Kernel-ready operands; see ``prepare`` for shapes."""
 
     qs: jnp.ndarray          # (Qp, D) f32 sorted+padded queries
-    pts: jnp.ndarray         # (G*P, D) f32 grouped points, centered per group
-    ok: jnp.ndarray          # (G*P,) bool slot validity
+    pts: jnp.ndarray         # (D+1, G*P) f32 grouped points (pack_points)
     order: jnp.ndarray       # (nqb, G) int32 group visit order per block
     glb: jnp.ndarray         # (nqb, G) f32 group lower bounds, ascending
-    centers: jnp.ndarray     # (G, D) f32 group centers (0 for dead groups)
     inv: jnp.ndarray         # (Q,) int32 undoes the query sort
     block_q: int
     points_per_group: int
@@ -80,12 +106,7 @@ def prepare(pts, valid, active, bbox_lo, bbox_hi, queries, *,
     glo = lo_f.reshape(G, block_r, D).min(axis=1)          # (G, D)
     ghi = hi_f.reshape(G, block_r, D).max(axis=1)
     galive = glo[:, 0] <= ghi[:, 0]
-    # Midpoint center: glo + ghi is exact for coords < 2^23 (sum < 2^24)
-    # and the * 0.5 never rounds, so centers inherit the data's exactness.
-    centers = jnp.where(galive[:, None], (glo + ghi) * jnp.float32(0.5), 0.0)
-
-    pts_g = (pts_f.reshape(G, P, D) - centers[:, None, :]).reshape(G * P, D)
-    ok_g = ok.reshape(G * P)
+    pk = pack_points(pts_f.reshape(G * P, D), ok.reshape(G * P))
 
     Q = queries.shape[0]
     qf = queries.astype(jnp.float32)
@@ -107,6 +128,5 @@ def prepare(pts, valid, active, bbox_lo, bbox_hi, queries, *,
     order = jnp.argsort(glb, axis=1).astype(jnp.int32)     # (nqb, G)
     glb = jnp.take_along_axis(glb, order, axis=1)
 
-    return FrontierPrep(qs=qs, pts=pts_g, ok=ok_g, order=order, glb=glb,
-                        centers=centers, inv=inv, block_q=block_q,
-                        points_per_group=P)
+    return FrontierPrep(qs=qs, pts=pk, order=order, glb=glb, inv=inv,
+                        block_q=block_q, points_per_group=P)

@@ -7,9 +7,9 @@ visit prefix (the ``while_loop`` stops at the first failed lower bound —
 exactly the set of steps the kernel's ``pl.when`` lets through).
 
 This is also the fast CPU spelling behind ``impl="auto"``: one argsort
-over G groups per query *block* and contiguous ``dynamic_slice`` tiles
-fed to BLAS, versus the chunked frontier's per-query argsort over all R
-rows and gather-heavy chunk bodies.
+over G groups per query *block* and contiguous ``dynamic_slice`` tiles,
+versus the chunked frontier's per-query argsort over all R rows and
+gather-heavy chunk bodies.
 """
 
 from __future__ import annotations
@@ -40,10 +40,8 @@ def knn_frontier_ref(pr: FrontierPrep, *, k: int):
         def body(st):
             j, dist, idx = st
             g = order_b[j]
-            c = jax.lax.dynamic_slice_in_dim(pr.centers, g, 1)   # (1, D)
-            p = jax.lax.dynamic_slice_in_dim(pr.pts, g * P, P)   # (P, D)
-            okt = jax.lax.dynamic_slice_in_dim(pr.ok, g * P, P)
-            d2 = _tile_distances(qb - c, p, okt)
+            pk = jax.lax.dynamic_slice_in_dim(pr.pts, g * P, P, axis=1)
+            d2 = _tile_distances(qb, pk)
             ids = g * P + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
             dist, idx = _merge_topk(dist, idx, d2, ids, k)
             return j + 1, dist, idx

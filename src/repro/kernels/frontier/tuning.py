@@ -17,8 +17,8 @@ _DEFAULT_TILES = {
     # CPU while_loop spelling: small query blocks keep the early exit
     # tight (one straggler query can't pin a whole block on the scan).
     "ref": (8, 512),
-    # MXU spellings: 128-query tiles amortize the point-tile reads and
-    # match the MXU's 128-lane geometry.
+    # kernel spellings: 128-query tiles amortize the point-tile reads
+    # (never swept on a TPU).
     "pallas": (128, 512),
     "pallas-interpret": (16, 512),
 }

@@ -4,6 +4,7 @@ import functools
 
 import jax
 
+from ..frontier.prep import rescore
 from .kernel import knn_pallas
 from .ref import knn_ref
 
@@ -33,13 +34,13 @@ def knn_bruteforce_impl(queries, points, ok, *, k: int, block_q: int = 128,
     impl = canonical_impl(impl)
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "pallas":
-        return knn_pallas(queries, points, ok, k=k, block_q=block_q,
-                          block_p=block_p)
-    if impl == "pallas-interpret":
-        return knn_pallas(queries, points, ok, k=k, block_q=block_q,
-                          block_p=block_p, interpret=True)
-    return knn_ref(queries, points, ok, k=k)
+    if impl == "ref":
+        return knn_ref(queries, points, ok, k=k)
+    _, ids = knn_pallas(queries, points, ok, k=k, block_q=block_q,
+                        block_p=block_p,
+                        interpret=(impl == "pallas-interpret"))
+    # the oracle's distance expression, whatever the tile's rounding
+    return rescore(points, queries, ids)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_q", "block_p",

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.configs import platform
 from repro.data import points as gen
 from repro.models import transformer
 from repro.serve import ServeEngine
@@ -99,6 +100,7 @@ def main(argv=None):
     ap.add_argument("--prompt", type=int, default=32)
     ap.add_argument("--new", type=int, default=16)
     args = ap.parse_args(argv)
+    platform.use_compile_cache()
     (serve_index if args.service == "index" else serve_lm)(args)
 
 

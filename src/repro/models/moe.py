@@ -29,11 +29,6 @@ from repro.sharding import constraints as cstr
 
 from .layers import rms_norm
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 P = jax.sharding.PartitionSpec
 
 
@@ -132,10 +127,10 @@ def _moe_shard_map(h, p, cfg, moe, mesh):
         y = y.reshape(-1, D)[:T].reshape(Bl, S, D)
         return jax.lax.psum(y, "model")               # ONLY collective
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(dp or None, None, None), P(), P("model"),
-                             P("model"), P("model")),
-                   out_specs=P(dp or None, None, None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(dp or None, None, None), P(), P("model"),
+                                 P("model"), P("model")),
+                       out_specs=P(dp or None, None, None), check_vma=False)
     return fn(h, p["wr"], p["w1"], p["w3"], p["w2"])
 
 

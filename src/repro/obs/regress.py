@@ -166,6 +166,9 @@ def _suite_dist(verbose: bool) -> dict:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in ("src", env.get("PYTHONPATH", "")) if p)
+        # a simulated CPU mesh: never contend for an accelerator that
+        # this parent process already holds
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run(
             [sys.executable, "-m", "repro.serving.driver", "--smoke",
              "--mesh", str(n_shards), "--json", path],

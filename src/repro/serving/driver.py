@@ -85,6 +85,9 @@ class DriverCfg:
     dim: int = 2
     phi: int = 32
     mesh: int = 0             # simulated shard count (0 = single-device)
+    # kNN spelling the requests ask for; "auto" is the served route, and
+    # "pallas-frontier-interpret" rehearses the kernel path on the CPU
+    knn_impl: str = "auto"
 
 
 def _query_stream(cfg: DriverCfg, scenario: str, step: int):
@@ -102,11 +105,13 @@ def _query_stream(cfg: DriverCfg, scenario: str, step: int):
 
 
 def run_one(kind: str, scenario: str, cfg: DriverCfg,
-            verbose: bool = False, mesh=None) -> dict:
+            verbose: bool = False, mesh=None, return_server: bool = False):
     """Replay one (backend, scenario) trace; returns latency summary +
     sustained throughput for the measured window. With ``mesh`` the
     server's head index is mesh-sharded (``DistributedIndex``) and the
-    summary gains a per-shard ``distributed`` section."""
+    summary gains a per-shard ``distributed`` section. With
+    ``return_server`` the result is ``(summary, server)``, the server
+    committed at the trace's last step — for checking what it holds."""
     total = cfg.warmup + cfg.steps
     trace = gen.make_trace(scenario, seed=cfg.seed, n=cfg.n,
                            batch=cfg.batch, steps=total, dim=cfg.dim)
@@ -138,7 +143,8 @@ def run_one(kind: str, scenario: str, cfg: DriverCfg,
         # overlapping the in-flight updates on device
         qpts, lo, hi = _query_stream(cfg, scenario, s)
         t1 = time.perf_counter()
-        knn_tickets = [batcher.submit_knn(qpts[i], cfg.k)
+        knn_tickets = [batcher.submit_knn(qpts[i], cfg.k,
+                                          impl=cfg.knn_impl)
                        for i in range(cfg.queries)]
         answers = [t.result() for t in knn_tickets]
         t2 = time.perf_counter()       # dispatched: host work done
@@ -220,7 +226,7 @@ def run_one(kind: str, scenario: str, cfg: DriverCfg,
                   f"points/shard min={d['shard_min_points']} "
                   f"max={d['shard_max_points']} "
                   f"dropped={d['dropped']}", flush=True)
-    return out
+    return (out, srv) if return_server else out
 
 
 def run(kinds=DEFAULT_KINDS, scenarios=gen.SCENARIOS,
@@ -397,10 +403,12 @@ def main(argv=None):
                     default=DriverCfg.max_delay_ms)
     ap.add_argument("--seed", type=int, default=DriverCfg.seed)
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
-                    help="serve from a DistributedIndex sharded over a "
-                    "simulated N-device CPU mesh (stages "
+                    help="serve from a DistributedIndex sharded over the "
+                    "first N devices and add per-shard metrics: the "
+                    "accelerator's chips where one is present, else N "
+                    "simulated CPU devices (stages "
                     "--xla_force_host_platform_device_count before jax "
-                    "initializes; adds per-shard metrics)")
+                    "initializes)")
     ap.add_argument("--json", nargs="?", const=DEFAULT_JSON, default=None,
                     metavar="PATH", help="write the latency/throughput "
                     f"payload (default {DEFAULT_JSON})")
@@ -419,13 +427,14 @@ def main(argv=None):
                     "into batcher-wait/dispatch/device segments "
                     f"(default {DEFAULT_SERVE_TRACE})")
     args = ap.parse_args(argv)
+    from ..configs import platform
     mesh = None
     if args.mesh:
         # must precede anything that initializes the jax backend (the
         # module-level jax import above is fine — topology locks at the
         # first devices()/array op, not at import)
-        from ..configs import platform
         mesh = platform.simulate_mesh(args.mesh)
+    platform.use_compile_cache()
     rec_obs = obs.install(obs.Recorder()) if args.obs_trace else None
 
     def _export_obs():

@@ -10,9 +10,7 @@ FSDP resolution: gather the (small) weight shard, keep tokens sharded.
 All helpers no-op when no ambient mesh is set (single-device tests) and
 silently drop axes that don't exist or don't divide — the same model
 code runs everywhere. Launchers call :func:`set_ambient_mesh` (dryrun
-does it per cell), which spells ``jax.sharding.set_mesh`` on jax >= 0.5
-and falls back to the thread-resources mesh context on jax 0.4.x,
-where ``get_abstract_mesh``/``set_mesh`` don't exist yet.
+does it per cell).
 """
 
 from __future__ import annotations
@@ -24,29 +22,14 @@ TP = "model"
 BATCH_AXES = ("pod", "data")
 
 
-def _abstract_mesh():
-    """jax.sharding.get_abstract_mesh, shimmed for jax 0.4.x (where the
-    ambient mesh lives in the thread-resources env instead)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as mesh_lib
-    m = getattr(mesh_lib.thread_resources.env, "physical_mesh", None)
-    return None if m is None or m.empty else m
-
-
 def set_ambient_mesh(mesh):
-    """Make ``mesh`` ambient for :func:`constrain` (version-portable
-    spelling of ``jax.sharding.set_mesh``). Process-lifetime: launcher
-    use only."""
-    if hasattr(jax.sharding, "set_mesh"):
-        jax.sharding.set_mesh(mesh)
-    else:  # jax 0.4.x: hold the Mesh context open for the process
-        mesh.__enter__()
+    """Make ``mesh`` ambient for :func:`constrain`
+    (``jax.sharding.set_mesh``). Process-lifetime: launcher use only."""
+    jax.sharding.set_mesh(mesh)
 
 
 def _mesh():
-    am = _abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or not am.axis_names:
         return None
     return am
@@ -58,8 +41,7 @@ def constrain(x, *spec):
     am = _mesh()
     if am is None:
         return x
-    shape = dict(zip(am.axis_names, am.shape.values())) \
-        if hasattr(am.shape, "values") else dict(am.shape)
+    shape = dict(am.shape)
     clean = []
     for i, s in enumerate(spec):
         if s is None:

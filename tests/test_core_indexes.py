@@ -165,6 +165,22 @@ def test_duplicates_multiset_semantics(kind):
     check_queries(t.view(), live, rng)
 
 
+def test_spac_delete_finds_point_after_split_below_duplicate_run():
+    """A run of rows sharing one min_code, with a larger code in its last
+    row: a split below the run moves the run's first row to a higher
+    free row id. The directory must still put the larger code's row last
+    in the run, or delete routes past it and misses the point."""
+    pts = np.array([[0, 1]] + [[1, 1]] * 95, np.int32)   # codes 3, 2 x95
+    t = spac.build(jnp.asarray(pts), phi=8, bits=12, coord_bits=12,
+                   capacity_rows=256)
+    # code 0 routes below every row: row 0 splits into fresh, higher rows
+    t = spac.insert(t, jnp.zeros((32, 2), jnp.int32))
+    t = spac.delete(t, jnp.asarray(pts[:32]))
+    live = live_points(t.view())
+    assert int(t.size) == live.shape[0] == 96
+    assert not (live == [0, 1]).all(axis=1).any()
+
+
 @pytest.mark.parametrize("kind", INDEX_KINDS)
 def test_incremental_equals_bulk(kind):
     """insert(build(P), Q) answers every query identically to build(P u Q)."""

@@ -38,7 +38,7 @@ def direct_build(kind, pts, cap):
                            capacity_rows=cap)
     if kind in ("spac-h", "spac-z", "spac-m", "cpam-h", "cpam-z"):
         return spac.build(pts, phi=PHI, curve=get_backend(kind).curve,
-                          bits=16, coord_bits=30, capacity_rows=cap)
+                          bits=16, coord_bits=20, capacity_rows=cap)
     if kind == "kd":
         return baselines.kd_build(pts, phi=PHI, max_depth=24,
                                   capacity_rows=cap)

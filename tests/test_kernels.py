@@ -184,15 +184,21 @@ def test_knn_kernel_rejects_legacy_interpret_alias():
 # fused frontier knn
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("R,C,dim,Q,k,bq,bp", [
-    (37, 16, 2, 33, 8, 8, 64),      # ragged everything
-    (64, 8, 3, 16, 4, 16, 128),     # 3-d, whole blocks
-    (5, 4, 2, 7, 32, 8, 8),         # k > live points
+@pytest.mark.parametrize("R,C,dim,Q,k,bq,bp,entries", [
+    (37, 16, 2, 33, 8, 8, 64, None),      # ragged everything
+    (64, 8, 3, 16, 4, 16, 128, None),     # 3-d, whole blocks
+    (5, 4, 2, 7, 32, 8, 8, None),         # k > live points
+    (96, 8, 2, 40, 6, 8, 16, 15),         # 3 steps per launch, 16 launches
 ])
-def test_frontier_kernel_interpret_matches_ref(R, C, dim, Q, k, bq, bp):
+def test_frontier_kernel_interpret_matches_ref(R, C, dim, Q, k, bq, bp,
+                                               entries, monkeypatch):
     """Interpret-mode fused kernel is bit-identical to its jnp mirror:
-    same prep, same tile expressions, same visit prefix."""
-    from repro.kernels.frontier import knn_frontier_impl
+    same prep, same tile expressions, same visit prefix — also when the
+    scalar-prefetch order is cut into many launches (``entries``)."""
+    from repro.kernels.frontier import kernel, knn_frontier_impl
+
+    if entries is not None:
+        monkeypatch.setattr(kernel, "PREFETCH_ENTRIES", entries)
 
     rng = np.random.default_rng(11)
     pts = jnp.asarray(rng.integers(0, 1 << 10, (R, C, dim)), jnp.int32)

@@ -7,18 +7,17 @@ Pallas brute-force flat scan) must agree bit-for-bit with each other
 and with a numpy oracle.
 
 The parity data uses integer coordinates < 2^10 so every intermediate
-of both distance formulas (the frontier's (q-p)^2 sum and the kernel's
-|q|^2 - 2qp + |p|^2 MXU form) is an integer below 2^24 — exactly
-representable in float32 — and the seed is chosen so no query has a
-tie at the k boundary. Under those conditions "identical ids/d2" is
-well-defined and asserted with assert_array_equal.
+of the distance sum (q-p)^2 — the one expression every route evaluates
+— is an integer below 2^24, exactly representable in float32, and the
+seed is chosen so no query has a tie at the k boundary. Under those
+conditions "identical ids/d2" is well-defined and asserted with
+assert_array_equal.
 
-The fused frontier kernel (impl="pallas-frontier") carries a stronger
-guarantee: its *centered* MXU identity subtracts the per-group bbox
-midpoint before the matmul, so exactness needs only the tile-local
-spread in the window, not the absolute coordinates — asserted by the
-adversarial large-magnitude test below, where the plain identity is
-off by orders of magnitude.
+Because the kernels compute (q-p)^2 directly (no |q|^2 - 2qp + |p|^2
+expansion), exactness needs only the *differences* in the f32-exact
+window, not the absolute coordinates — asserted by the adversarial
+large-magnitude test below, where the expanded identity is off by
+orders of magnitude.
 """
 
 from __future__ import annotations
@@ -136,7 +135,7 @@ def test_knn_engine_rejects_legacy_interpret_alias():
 
 
 # ---------------------------------------------------------------------------
-# compensated distances: exact outside the absolute f32 window
+# direct distances: exact outside the absolute f32 window
 # ---------------------------------------------------------------------------
 
 _ADV_OFFSET = 1 << 23       # every coordinate far outside |q|^2 exactness
@@ -146,7 +145,7 @@ _ADV_SPREAD = 1 << 9        # tile-local spread well inside the window
 def _adversarial_data(n: int, q: int, k: int):
     """Tie-free points/queries at offset 2^23 with spread < 2^9: every
     coordinate is an exactly-representable f32 integer, (q-p) stays
-    exact (< 2^10), but |q|^2 ~ 7e13 has ulp 2^23 — the plain MXU
+    exact (< 2^10), but |q|^2 ~ 7e13 has ulp 2^23 — the expanded
     identity cannot even represent its own intermediates."""
     for seed in range(64):
         rng = np.random.default_rng(seed + 100)
@@ -163,7 +162,7 @@ def _adversarial_data(n: int, q: int, k: int):
 
 def test_plain_mxu_identity_rounds_at_large_magnitude():
     """Precondition for the parity test below: on the adversarial data
-    the *uncentered* |q|^2 - 2qp + |p|^2 form diverges from the exact
+    the expanded |q|^2 - 2qp + |p|^2 form diverges from the exact
     (q-p)^2 distances — catastrophically, not in the last ulp."""
     pts, qs = _adversarial_data(300, 8, K)
     exact = ((pts[None].astype(np.int64)
@@ -180,8 +179,8 @@ def test_plain_mxu_identity_rounds_at_large_magnitude():
 def test_knn_compensated_parity_outside_f32_window(kind):
     """impl="pallas-frontier" (and its interpret spelling) is bit-exact
     against impl="frontier" and the int64 oracle on coordinates far
-    outside the absolute f32-exact window: the centered identity only
-    needs the tile-local spread in the window."""
+    outside the absolute f32-exact window: the direct (q-p)^2 only needs
+    the differences in the window."""
     pts, qs = _adversarial_data(300, 8, K)
     idx = make_index(kind, jnp.asarray(pts), phi=PHI)
     want_d2 = oracle_knn_d2(pts, qs, K)
