@@ -221,47 +221,52 @@ class QueryEngine:
             impl: str = "auto"):
         """Exact batched kNN -> (d2 (Q, k) ascending, flat ids (Q, k) =
         row*C+slot, -1 padded), canonically (d2, id)-ordered."""
-        rows, cols, dim = view.pts.shape
-        route, param = self.plan_knn(rows, cols, impl)
-        obs.count("engine.plan_request")
-        obs.count(f"engine.route.{route}")
-        fn = _knn_closure(qpts.shape[0], dim, str(qpts.dtype), int(k),
-                          route, param)
-        # opt-in compile-cost attribution (repro.obs.costs): charge this
-        # plan's flops/bytes once per signature at the site that owns
-        # the plan_miss counter; no-op on the default recorder. The view
-        # shape is part of the signature — the compiled program (and so
-        # its cost) depends on R x C, not just the closure-cache key.
-        obs.costs.capture(
-            fn, (view, qpts),
-            f"knn.q{qpts.shape[0]}.k{int(k)}.{route}-{param}"
-            f".v{rows}x{cols}")
-        return fn(view, qpts)
+        with obs.span("engine.knn"):
+            rows, cols, dim = view.pts.shape
+            route, param = self.plan_knn(rows, cols, impl)
+            obs.count("engine.plan_request")
+            obs.count(f"engine.route.{route}")
+            fn = _knn_closure(qpts.shape[0], dim, str(qpts.dtype), int(k),
+                              route, param)
+            # opt-in compile-cost attribution (repro.obs.costs): charge
+            # this plan's flops/bytes once per signature at the site that
+            # owns the plan_miss counter; no-op on the default recorder.
+            # The view shape is part of the signature — the compiled
+            # program (and so its cost) depends on R x C, not just the
+            # closure-cache key.
+            obs.costs.capture(
+                fn, (view, qpts),
+                f"knn.q{qpts.shape[0]}.k{int(k)}.{route}-{param}"
+                f".v{rows}x{cols}")
+            return fn(view, qpts)
 
     def range_count(self, view: queries.LeafView, lo, hi):
         """Exact batched range count -> counts (Q,). Escalates the row
         buffer through power-of-two buckets until nothing truncates."""
-        rows = view.pts.shape[0]
-        key = ("range_count", lo.shape[0], lo.shape[-1], str(lo.dtype))
-        max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
-                       _pow2(rows))
-        obs.count("engine.plan_request")
-        rounds = 0
-        while True:
-            fn = _range_count_closure(lo.shape[0], lo.shape[-1],
-                                      str(lo.dtype), max_rows)
-            obs.costs.capture(
-                fn, (view, lo, hi),
-                f"range_count.q{lo.shape[0]}.r{max_rows}"
-                f".v{rows}x{view.pts.shape[1]}")
-            cnt, trunc = fn(view, lo, hi)
-            if max_rows >= rows or not bool(jnp.any(trunc)):
-                self._buckets[key] = max_rows
-                obs.observe("engine.escalation_rounds", rounds)
-                return cnt
-            rounds += 1
-            obs.count("engine.escalation")
-            max_rows = min(2 * max_rows, _pow2(rows))
+        with obs.span("engine.range_count"):
+            rows = view.pts.shape[0]
+            key = ("range_count", lo.shape[0], lo.shape[-1], str(lo.dtype))
+            max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
+                           _pow2(rows))
+            obs.count("engine.plan_request")
+            rounds = 0
+            while True:
+                fn = _range_count_closure(lo.shape[0], lo.shape[-1],
+                                          str(lo.dtype), max_rows)
+                obs.costs.capture(
+                    fn, (view, lo, hi),
+                    f"range_count.q{lo.shape[0]}.r{max_rows}"
+                    f".v{rows}x{view.pts.shape[1]}")
+                cnt, trunc = fn(view, lo, hi)
+                with obs.span("engine.range_count.sync"):
+                    done = max_rows >= rows or not bool(jnp.any(trunc))
+                if done:
+                    self._buckets[key] = max_rows
+                    obs.observe("engine.escalation_rounds", rounds)
+                    return cnt
+                rounds += 1
+                obs.count("engine.escalation")
+                max_rows = min(2 * max_rows, _pow2(rows))
 
     def range_list(self, view: queries.LeafView, lo, hi):
         """Exact batched range report -> (ids (Q, cap) flat row*C+slot
@@ -289,8 +294,9 @@ class QueryEngine:
                 f"range_list.q{lo.shape[0]}.r{max_rows}.c{cap}"
                 f".v{rows}x{cols}")
             ids, cnt, rows_trunc = fn(view, lo, hi)
-            need_rows = max_rows < rows and bool(jnp.any(rows_trunc))
-            max_cnt = int(jnp.max(cnt)) if cnt.size else 0
+            with obs.span("engine.range_list.sync"):
+                need_rows = max_rows < rows and bool(jnp.any(rows_trunc))
+                max_cnt = int(jnp.max(cnt)) if cnt.size else 0
             need_cap = cap < max_cnt
             if not (need_rows or need_cap):
                 self._buckets[key] = (max_rows, cap)
@@ -313,36 +319,40 @@ class QueryEngine:
         shard answers locally (frontier or flat scan — unjitted inside
         shard_map), then the merge takes the top-k of per-shard top-k."""
         from . import distributed as D
-        rows, cols = index.tree.pts.shape[-3], index.tree.pts.shape[-2]
-        route, param = self.plan_knn(rows, cols, impl)
-        obs.count("engine.plan_request")
-        obs.count(f"engine.route.{route}")
-        if route == "frontier":
-            return D.knn(index, qpts, k, mesh, chunk=param)
-        if route == "pallas-frontier":
-            return D.knn(index, qpts, k, mesh, impl="pallas-frontier",
-                         kernel=param)
-        return D.knn(index, qpts, k, mesh, impl="flat", kernel=param)
+        with obs.span("engine.knn"):
+            rows, cols = index.tree.pts.shape[-3], index.tree.pts.shape[-2]
+            route, param = self.plan_knn(rows, cols, impl)
+            obs.count("engine.plan_request")
+            obs.count(f"engine.route.{route}")
+            if route == "frontier":
+                return D.knn(index, qpts, k, mesh, chunk=param)
+            if route == "pallas-frontier":
+                return D.knn(index, qpts, k, mesh, impl="pallas-frontier",
+                             kernel=param)
+            return D.knn(index, qpts, k, mesh, impl="flat", kernel=param)
 
     def range_count_dist(self, index, lo, hi, mesh):
         """Exact distributed range count -> counts (Q,): per-shard
         count + psum, re-run at escalated row buckets until no shard
         truncates."""
         from . import distributed as D
-        rows = index.tree.pts.shape[-3]
-        key = ("range_count_dist", lo.shape[0], lo.shape[-1],
-               str(lo.dtype))
-        max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
-                       _pow2(rows))
-        obs.count("engine.plan_request")
-        rounds = 0
-        while True:
-            cnt, trunc = D.range_count(index, lo, hi, mesh,
-                                       max_rows=max_rows)
-            if max_rows >= rows or not bool(jnp.any(trunc)):
-                self._buckets[key] = max_rows
-                obs.observe("engine.escalation_rounds", rounds)
-                return cnt
-            rounds += 1
-            obs.count("engine.escalation")
-            max_rows = min(2 * max_rows, _pow2(rows))
+        with obs.span("engine.range_count"):
+            rows = index.tree.pts.shape[-3]
+            key = ("range_count_dist", lo.shape[0], lo.shape[-1],
+                   str(lo.dtype))
+            max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
+                           _pow2(rows))
+            obs.count("engine.plan_request")
+            rounds = 0
+            while True:
+                cnt, trunc = D.range_count(index, lo, hi, mesh,
+                                           max_rows=max_rows)
+                with obs.span("engine.range_count.sync"):
+                    done = max_rows >= rows or not bool(jnp.any(trunc))
+                if done:
+                    self._buckets[key] = max_rows
+                    obs.observe("engine.escalation_rounds", rounds)
+                    return cnt
+                rounds += 1
+                obs.count("engine.escalation")
+                max_rows = min(2 * max_rows, _pow2(rows))

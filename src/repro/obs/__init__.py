@@ -23,7 +23,7 @@ Usage::
     obs.observe("batch_rows", 512)      # pow2-bucket histogram
     ...
     obs.resolve()                       # at a barrier: one read each
-    obs.export_chrome_trace(rec, "trace.json")   # Perfetto-viewable
+    obs.write_chrome_trace(rec, "trace.json")    # Perfetto-viewable
     # then: python -m repro.obs.view trace.json
 
 Disabled (no recorder installed) every helper is a near-free no-op:
@@ -39,13 +39,30 @@ Instrumented out of the box (counter/span names are stable API):
                                       ``repro.core.engine.trace_count``
 ``engine.route.frontier|flat``        kNN impl routing decisions
 ``engine.escalation_rounds``          pow2 buffer escalations per call
+``engine.knn`` span                   one kNN call: plan lookup and
+                                      dispatch
+``engine.range_count`` span           one range-count call: plan,
+                                      dispatch, escalation rounds
+``engine.range_count.sync`` span      the truncation read of each round
+                                      (a host wait on the device)
+``engine.range_list.sync`` span       range-list's truncation and
+                                      count reads of each round
 ``index.update_plan_miss``            update-closure compiles
 ``index.grow/compact/build_retry``    capacity-recovery ladder events
 ``serving.insert|delete`` spans       update dispatch latency
 ``serving.evict_block`` span          version-window backpressure stall
 ``serving.replay`` span               deferred-overflow replays
-``serving.commit`` span               exposed commit stall
-``batcher.queue_depth`` gauge         rows pending at each enqueue
+``serving.commit`` span               exposed commit stall, in phases:
+``serving.commit.wait`` span          ``block_until_ready`` on the head
+``serving.commit.check`` span         overflow/dropped and deferred
+                                      point reads
+``serving.commit.reclaim`` span       dropping versions, memory rebase
+``serving.commit.resolve`` span       draining deferred obs reads
+``batcher.flush`` span                one op group of a flush, in
+                                      phases:
+``batcher.pack`` span                 concatenate, pad, to the device
+``batcher.call`` span                 the engine call
+``batcher.split`` span                slicing each ticket's answer
 ``batcher.coalesce_rows/pad_rows``    flush batch size / pad waste
 ``batcher.wait_s``                    request queue wait (submit->flush)
 ``batcher.flush.<reason>``            size|deadline|result|retarget|
@@ -59,6 +76,11 @@ Instrumented out of the box (counter/span names are stable API):
                                       (``memory_snapshots=True``)
 ====================================  =================================
 
+With ``Recorder(annotate=True)`` every span is also written into the
+JAX profiler's trace (``jax.profiler.TraceAnnotation``), nested as it
+ran, on the clock of the device ops: a profiler trace then says what
+the host was doing in each gap between device programs.
+
 Phase 2 adds three memory/cost/drift surfaces (ROADMAP "Observability"):
 :mod:`repro.obs.memory` (``nbytes``-metadata accounting — sync-free by
 construction), :mod:`repro.obs.costs` (AOT compile-cost capture at
@@ -71,8 +93,7 @@ from __future__ import annotations
 import contextlib
 
 from . import costs
-from .export import (chrome_trace, jsonl_records, write_chrome_trace,
-                     write_jsonl)
+from .export import chrome_trace, write_chrome_trace
 from .memory import fmt_bytes, tree_bytes
 from .record import NULL_SPAN, Hist, NullSpan, Recorder, Span, pow2_bucket
 
@@ -80,7 +101,7 @@ __all__ = [
     "Recorder", "Span", "NullSpan", "NULL_SPAN", "Hist", "pow2_bucket",
     "install", "uninstall", "recording", "enabled", "recorder",
     "span", "count", "gauge", "observe", "defer", "resolve",
-    "chrome_trace", "jsonl_records", "write_chrome_trace", "write_jsonl",
+    "chrome_trace", "write_chrome_trace",
     "costs", "tree_bytes", "fmt_bytes",
 ]
 

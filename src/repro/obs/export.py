@@ -1,19 +1,17 @@
-"""Exporters: JSON-lines and Chrome trace-event format.
+"""Exporter: the Chrome trace-event format.
 
-Two on-disk shapes, both derived from :meth:`Recorder.report` /
-``Recorder.events`` (so exporting resolves deferred device reads — it
-is a report barrier):
+Derived from :meth:`Recorder.report` / ``Recorder.events`` (so exporting
+resolves deferred device reads — it is a report barrier):
+:func:`write_chrome_trace` writes the ``{"traceEvents": [...]}`` JSON
+that chrome://tracing and Perfetto (https://ui.perfetto.dev) load
+directly. Spans become complete ("ph": "X") events with microsecond
+timestamps, each with its enclosing span's name as ``args.parent``;
+counters, gauges and histogram summaries ride in ``otherData`` so the
+summary CLI (:mod:`repro.obs.view`) can reconstruct the full report
+from the trace file alone.
 
-* **JSONL** (:func:`write_jsonl`): one object per line — a ``meta``
-  line, then every span in timeline order, then ``counter`` /
-  ``gauge`` / ``hist`` lines. Grep- and pandas-friendly.
-* **Chrome trace events** (:func:`write_chrome_trace`): the
-  ``{"traceEvents": [...]}`` JSON that chrome://tracing and Perfetto
-  (https://ui.perfetto.dev) load directly. Spans become complete
-  ("ph": "X") events with microsecond timestamps; counters, gauges and
-  histogram summaries ride in ``otherData`` so the summary CLI
-  (:mod:`repro.obs.view`) can reconstruct the full report from the
-  trace file alone.
+The other format in use is the JAX profiler's own trace, which holds
+the spans of a ``Recorder(annotate=True)`` beside the device ops.
 """
 
 from __future__ import annotations
@@ -30,29 +28,6 @@ def _ensure_dir(path: str) -> None:
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
 
 
-def jsonl_records(rec: Recorder) -> list[dict]:
-    report = rec.report()                     # resolves deferred reads
-    out: list[dict] = [{"type": "meta", "version": TRACE_VERSION,
-                        "wall_s": report["wall_s"]}]
-    for ev in rec.events:
-        out.append({"type": "span", **ev})
-    for name, value in report["counters"].items():
-        out.append({"type": "counter", "name": name, "value": value})
-    for name, g in report["gauges"].items():
-        out.append({"type": "gauge", "name": name, **g})
-    for name, h in report["hists"].items():
-        out.append({"type": "hist", "name": name, **h})
-    return out
-
-
-def write_jsonl(rec: Recorder, path: str) -> str:
-    _ensure_dir(path)
-    with open(path, "w") as f:
-        for record in jsonl_records(rec):
-            f.write(json.dumps(record, sort_keys=True) + "\n")
-    return path
-
-
 def chrome_trace(rec: Recorder, pid: int = 1, tid: int = 1) -> dict:
     report = rec.report()                     # resolves deferred reads
     events = []
@@ -60,8 +35,11 @@ def chrome_trace(rec: Recorder, pid: int = 1, tid: int = 1) -> dict:
         out = {"name": ev["name"], "ph": "X", "pid": pid, "tid": tid,
                "ts": ev["ts"] * 1e6, "dur": ev["dur"] * 1e6,
                "cat": ev.get("cat", "obs")}
-        if "args" in ev:
-            out["args"] = ev["args"]
+        args = dict(ev.get("args", {}))
+        if "parent" in ev:
+            args["parent"] = ev["parent"]
+        if args:
+            out["args"] = args
         events.append(out)
     # counters as Chrome counter ("C") samples at end-of-run so the
     # totals are visible on the timeline too

@@ -3,8 +3,10 @@ deferred device-read list.
 
 Everything here is host-side Python over stdlib types — the subsystem
 is zero-dependency by design (``jax`` is touched only inside
-:meth:`Recorder.resolve`, the one sanctioned sync point) so enabling it
-can never change what the instrumented code compiles or dispatches.
+:meth:`Recorder.resolve`, the one sanctioned sync point, and by the
+profiler sink of ``Recorder(annotate=True)``, which only writes host
+annotations) so enabling it can never change what the instrumented code
+compiles or dispatches.
 
 Two invariants this module owns (see ROADMAP "Observability"):
 
@@ -117,9 +119,15 @@ class Span:
     """One timed section. Use as a context manager (the common path) or
     drive ``begin()``/``end()`` by hand. ``set()`` adds attributes;
     ``defer()`` attaches an in-flight device value whose host read is
-    postponed to the owning recorder's :meth:`Recorder.resolve`."""
+    postponed to the owning recorder's :meth:`Recorder.resolve`.
 
-    __slots__ = ("rec", "name", "cat", "args", "t0", "dur")
+    ``parent`` is the name of the span that was open on the same thread
+    when this one began (None at the top). With ``Recorder(annotate=
+    True)`` the span also enters a ``jax.profiler.TraceAnnotation`` of
+    its name, so it lands in a profiler trace on the device ops' clock."""
+
+    __slots__ = ("rec", "name", "cat", "args", "t0", "dur", "parent",
+                 "_ann")
 
     def __init__(self, rec: "Recorder", name: str, cat: str, args: dict):
         self.rec = rec
@@ -128,8 +136,16 @@ class Span:
         self.args = args
         self.t0 = None
         self.dur = None
+        self.parent = None
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        stack = self.rec._stack()
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
+        if self.rec.annotate:
+            self._ann = self.rec._annotation(self.name)
+            self._ann.__enter__()
         self.t0 = self.rec.clock()
         return self
 
@@ -142,6 +158,14 @@ class Span:
 
     def end(self) -> None:
         self.dur = self.rec.clock() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        stack = self.rec._stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:               # ended out of order, by hand
+            stack.remove(self)
         self.rec._finish(self)
 
     def set(self, **attrs) -> "Span":
@@ -217,16 +241,28 @@ class Recorder:
     * ``capture_costs`` — :mod:`repro.obs.costs` AOT-compiles each new
       query/update plan once and records ``plan.cost.*`` counters;
       ``_cost_sigs`` tracks which plan signatures were already captured.
+
+    ``annotate`` (default off) is the profiler sink: every span also
+    enters and exits a ``jax.profiler.TraceAnnotation`` under its own
+    name, so a JAX profiler trace taken meanwhile holds the program's
+    spans on the host thread's line, on the same clock as the device
+    ops. It writes annotations only; it reads nothing from the device.
     """
 
     def __init__(self, clock=time.perf_counter, max_samples: int = 8192,
                  keep_events: bool = True, capture_costs: bool = False,
-                 memory_snapshots: bool = False):
+                 memory_snapshots: bool = False, annotate: bool = False):
         self.clock = clock
         self.keep_events = keep_events
         self.max_samples = max_samples
         self.capture_costs = capture_costs
         self.memory_snapshots = memory_snapshots
+        self.annotate = annotate
+        if annotate:
+            # deferred import: obs stays importable with the stdlib alone
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        self._local = threading.local()    # per-thread open-span stack
         self.events: list[dict] = []       # completed spans, in order
         self.counters: dict[str, float] = {}
         self.gauges: dict[str, dict] = {}  # name -> {value, max, n}
@@ -241,12 +277,21 @@ class Recorder:
     def span(self, name: str, cat: str = "", **attrs) -> Span:
         return Span(self, name, cat, attrs)
 
+    def _stack(self) -> list:
+        """The calling thread's open spans, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _finish(self, span: Span) -> None:
         if self.keep_events:
             ev = {"name": span.name, "ts": span.t0 - self.t0,
                   "dur": span.dur}
             if span.cat:
                 ev["cat"] = span.cat
+            if span.parent is not None:
+                ev["parent"] = span.parent
             if span.args:
                 ev["args"] = span.args
             with self._lock:

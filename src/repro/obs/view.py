@@ -1,8 +1,7 @@
 """Trace summary CLI: render an exported obs trace as tables.
 
-Loads either exporter format (Chrome trace-event JSON or JSONL — the
-format is sniffed, not flagged) and prints per-span latency stats,
-counters, gauges and histogram summaries. Exit status 0 iff the file
+Loads an exported Chrome trace-event JSON and prints per-span latency
+stats, counters, gauges and histogram summaries. Exit status 0 iff the file
 parses as an obs trace; CI uses that as the "exported trace is
 well-formed" check.
 
@@ -14,7 +13,7 @@ first. ``--top N`` limits both it and the default span table.
 Run::
 
     PYTHONPATH=src python -m repro.obs.view results/serve_trace.json
-    PYTHONPATH=src python -m repro.obs.view trace.jsonl --by-name --top 20
+    PYTHONPATH=src python -m repro.obs.view trace.json --by-name --top 20
 """
 
 from __future__ import annotations
@@ -25,7 +24,7 @@ import sys
 
 
 def load(path: str) -> dict:
-    """Normalize either exporter format to one report dict with keys
+    """Normalize an exported trace to one report dict with keys
     counters/gauges/hists/spans/events (+ wall_s); ``events`` are the
     raw timeline spans as ``{name, cat, dur_ms}``. Raises ValueError
     for anything that is not an obs trace."""
@@ -35,8 +34,8 @@ def load(path: str) -> dict:
         raise ValueError(f"{path}: empty file")
     try:
         payload = json.loads(text)
-    except json.JSONDecodeError:
-        return _from_jsonl(path, text)
+    except json.JSONDecodeError as exc:
+        raise ValueError(f"{path}: not JSON ({exc})") from None
     if isinstance(payload, dict) and "traceEvents" in payload:
         other = payload.get("otherData", {})
         for key in ("counters", "hists", "spans"):
@@ -51,55 +50,7 @@ def load(path: str) -> dict:
             for ev in payload["traceEvents"] if ev.get("ph") == "X"]
         return other
     raise ValueError(f"{path}: not an obs trace (expected a chrome "
-                     f"trace-event object or obs JSONL)")
-
-
-def _from_jsonl(path: str, text: str) -> dict:
-    counters: dict[str, float] = {}
-    gauges: dict[str, dict] = {}
-    hists: dict[str, dict] = {}
-    durs: dict[str, list[float]] = {}
-    events: list[dict] = []
-    meta: dict = {}
-    for i, line in enumerate(text.splitlines(), 1):
-        if not line.strip():
-            continue
-        try:
-            rec = json.loads(line)
-            kind = rec.pop("type")
-        except (json.JSONDecodeError, KeyError) as exc:
-            raise ValueError(f"{path}:{i}: bad obs JSONL record "
-                             f"({exc})") from None
-        if kind == "meta":
-            meta = rec
-        elif kind == "span":
-            durs.setdefault(rec["name"], []).append(rec["dur"])
-            events.append({"name": rec["name"],
-                           "cat": rec.get("cat", ""),
-                           "dur_ms": rec["dur"] * 1e3})
-        elif kind == "counter":
-            counters[rec["name"]] = rec["value"]
-        elif kind == "gauge":
-            gauges[rec.pop("name")] = rec
-        elif kind == "hist":
-            hists[rec.pop("name")] = rec
-        else:
-            raise ValueError(f"{path}:{i}: unknown record type {kind!r}")
-    spans = {}
-    for name, ds in sorted(durs.items()):
-        ds.sort()
-        n = len(ds)
-        spans[name] = {
-            "count": n, "total_ms": sum(ds) * 1e3,
-            "mean": sum(ds) / n * 1e3,
-            "p50": ds[n // 2] * 1e3,
-            "p95": ds[min(n - 1, int(0.95 * n))] * 1e3,
-            "p99": ds[min(n - 1, int(0.99 * n))] * 1e3,
-            "min": ds[0] * 1e3, "max": ds[-1] * 1e3,
-        }
-    return {"wall_s": meta.get("wall_s"), "counters": counters,
-            "gauges": gauges, "hists": hists, "spans": spans,
-            "events": events}
+                     f"trace-event object)")
 
 
 def by_name(events: list) -> dict:
@@ -178,7 +129,7 @@ def render(report: dict, top: int = 0) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("trace", help="obs trace file (chrome json or jsonl)")
+    ap.add_argument("trace", help="obs trace file (chrome trace json)")
     ap.add_argument("--top", type=int, default=0,
                     help="show only the N spans with the largest total")
     ap.add_argument("--by-name", action="store_true",

@@ -164,7 +164,6 @@ class MicroBatcher:
         now = self._clock()
         self._groups.setdefault(key, []).append((t, arrays, rows, now))
         self._pending_rows += rows
-        obs.gauge("batcher.queue_depth", self._pending_rows)
         if self._oldest is None:
             self._oldest = now
         if self._pending_rows >= self.max_batch:
@@ -218,25 +217,28 @@ class MicroBatcher:
     def _run_group(self, target, key: tuple, reqs: list, now) -> None:
         op = key[0]
         q = sum(r[2] for r in reqs)
-        obs.count("batcher.requests", len(reqs))
         obs.observe("batcher.coalesce_rows", q)
         obs.observe("batcher.pad_rows", _pow2(q) - q)
         for _, _, _, ts in reqs:
             obs.observe("batcher.wait_s", now - ts)
         with obs.span("batcher.flush", op=op, rows=q, reqs=len(reqs)):
-            cols = [_concat_pad([r[1][i] for r in reqs], q)
-                    for i in range(len(reqs[0][1]))]
-            if op == "knn":
-                # local indexes answer (d2, ids); distributed snapshots
-                # answer (d2, points, valid) — slice whatever came back
-                outs = tuple(target.knn(cols[0], key[1], impl=key[4]))
-            elif op == "range_count":
-                outs = (target.range_count(cols[0], cols[1]),)
-            else:
-                ids, cnt = target.range_list(cols[0], cols[1])
-                outs = (ids, cnt)
-        start = 0
-        for ticket, _, rows, _ts in reqs:
-            sl = tuple(o[start: start + rows] for o in outs)
-            ticket._resolve(sl if len(sl) > 1 else sl[0])
-            start += rows
+            with obs.span("batcher.pack"):
+                cols = [jnp.asarray(_concat_pad([r[1][i] for r in reqs], q))
+                        for i in range(len(reqs[0][1]))]
+            with obs.span("batcher.call"):
+                if op == "knn":
+                    # local indexes answer (d2, ids); distributed
+                    # snapshots answer (d2, points, valid) — slice
+                    # whatever came back
+                    outs = tuple(target.knn(cols[0], key[1], impl=key[4]))
+                elif op == "range_count":
+                    outs = (target.range_count(cols[0], cols[1]),)
+                else:
+                    ids, cnt = target.range_list(cols[0], cols[1])
+                    outs = (ids, cnt)
+            with obs.span("batcher.split"):
+                start = 0
+                for ticket, _, rows, _ts in reqs:
+                    sl = tuple(o[start: start + rows] for o in outs)
+                    ticket._resolve(sl if len(sl) > 1 else sl[0])
+                    start += rows

@@ -277,30 +277,34 @@ class SpatialServer:
     def commit(self) -> int:
         """Barrier: wait for the head version, run the deferred overflow
         check (replaying from the last good version on overflow), and
-        reclaim every older version. Returns the committed version id."""
+        reclaim every older version. Returns the committed version id.
+        Its phases are the ``serving.commit.*`` obs spans."""
         with obs.span("serving.commit") as sp:
             sp.set(version=self._head, in_flight=self._head - self._base)
             head = self._versions[self._head]
-            jax.block_until_ready(head.tree)
-            if _overflowed(head.tree) or (
-                    self._distributed
-                    and int(head.dropped) != self._base_dropped):
-                head = self._recover()
-            if self._deferred_points:
+            with obs.span("serving.commit.wait"):
+                jax.block_until_ready(head.tree)
+            with obs.span("serving.commit.check"):
                 # past the barrier these reads are free; see _live_rows
+                dirty = _overflowed(head.tree) or (
+                    self._distributed
+                    and int(head.dropped) != self._base_dropped)
                 self.stats["update_points"] += sum(
                     int(x) for x in self._deferred_points)
                 self._deferred_points = []
-            self._base, self._base_index = self._head, head
-            if self._distributed:
-                self._base_dropped = int(head.dropped)
-            self._log = []
-            self._versions = OrderedDict({self._head: head})
-            self._rebase_memory(head)
+            if dirty:
+                # the replay also resets the routing-slab baseline
+                head = self._recover()
+            with obs.span("serving.commit.reclaim"):
+                self._base, self._base_index = self._head, head
+                self._log = []
+                self._versions = OrderedDict({self._head: head})
+                self._rebase_memory(head)
             self.stats["commits"] += 1
             # commit is THE barrier: deferred obs device reads (span
             # attachments, deferred counters) resolve here for free
-            obs.resolve()
+            with obs.span("serving.commit.resolve"):
+                obs.resolve()
             return self._head
 
     def _recover(self) -> SpatialIndex:

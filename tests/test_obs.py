@@ -173,11 +173,12 @@ def test_batcher_coalesce_pad_and_flush_reasons():
         mb = MicroBatcher(idx, max_batch=1024, max_delay_s=10.0)
         tickets = [mb.submit_knn(_pts(1, seed=10 + i)[0], 3)
                    for i in range(5)]
-        assert rec.gauges["batcher.queue_depth"]["value"] == 5
+        assert mb.pending == 5
         mb.flush()
         [t.result() for t in tickets]
         assert rec.counters["batcher.flush.explicit"] == 1
-        assert rec.counters["batcher.requests"] == 5
+        (flush,) = [e for e in rec.events if e["name"] == "batcher.flush"]
+        assert flush["args"]["reqs"] == 5 and flush["args"]["rows"] == 5
         assert rec.hist("batcher.coalesce_rows").samples == [5.0]
         # pow2 padding: 5 rows pad to 8, so 3 wasted rows
         assert rec.hist("batcher.pad_rows").samples == [3.0]
@@ -225,33 +226,114 @@ def test_exporters_roundtrip_and_view_cli(tmp_path, capsys):
     rec = obs.Recorder()
     with obs.recording(rec):
         with obs.span("a", cat="x", n=1):
-            pass
+            with obs.span("b"):
+                pass
         obs.count("c", 2)
         obs.gauge("g", 3)
         obs.observe("h", 4.0)
     chrome = tmp_path / "trace.json"
-    lines = tmp_path / "trace.jsonl"
     obs.write_chrome_trace(rec, str(chrome))
-    obs.write_jsonl(rec, str(lines))
 
     data = json.loads(chrome.read_text())
-    (ev,) = [e for e in data["traceEvents"] if e["ph"] == "X"]
-    assert ev["name"] == "a" and ev["dur"] >= 0      # microseconds
+    spans = {e["name"]: e for e in data["traceEvents"] if e["ph"] == "X"}
+    assert set(spans) == {"a", "b"}
+    assert spans["a"]["dur"] >= spans["b"]["dur"] >= 0   # microseconds
+    assert spans["a"]["args"] == {"n": 1}
+    assert spans["b"]["args"] == {"parent": "a"}
     assert data["otherData"]["counters"]["c"] == 2
-    recs = [json.loads(ln) for ln in lines.read_text().splitlines()]
-    assert recs[0]["type"] == "meta"
-    kinds = {r["type"] for r in recs}
-    assert {"span", "counter", "gauge", "hist"} <= kinds
+    assert data["otherData"]["gauges"]["g"]["value"] == 3
+    assert data["otherData"]["hists"]["h"]["count"] == 1
 
-    for path in (chrome, lines):
-        assert view.main([str(path)]) == 0
-        out = capsys.readouterr().out
-        assert "a" in out and "c" in out
+    assert view.main([str(chrome)]) == 0
+    out = capsys.readouterr().out
+    assert "a" in out and "c" in out
     assert view.main([str(tmp_path / "missing.json")]) == 1
     bad = tmp_path / "bad.json"
     bad.write_text('{"nope": 1}')
     assert view.main([str(bad)]) == 1
+    lines = tmp_path / "old.jsonl"        # the retired JSONL format
+    lines.write_text('{"type": "meta"}\n{"type": "span"}\n')
+    assert view.main([str(lines)]) == 1
     capsys.readouterr()
+
+
+# -- span parents and the profiler sink -------------------------------------
+
+def test_spans_record_their_enclosing_span_per_thread():
+    import threading
+
+    def on_another_thread():
+        with obs.span("t"):               # "outer" is not open here
+            pass
+
+    rec = obs.Recorder()
+    with obs.recording(rec):
+        with obs.span("outer"):
+            with obs.span("mid"):
+                with obs.span("inner"):
+                    pass
+            other = threading.Thread(target=on_another_thread)
+            other.start()
+            other.join(timeout=10)
+            assert not other.is_alive()
+            sp = obs.span("manual").begin()
+            sp.end()
+    parents = {e["name"]: e.get("parent") for e in rec.events}
+    assert parents == {"inner": "mid", "mid": "outer", "outer": None,
+                       "t": None, "manual": "outer"}
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs its use."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_annotate_writes_each_span_into_the_profiler_trace(monkeypatch):
+    import jax.profiler
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    rec = obs.Recorder(annotate=True)
+    with obs.recording(rec):
+        with obs.span("serving.commit"):
+            with obs.span("serving.commit.wait"):
+                pass
+            obs.count("c")                # counters write no annotation
+        sp = obs.span("batcher.flush").begin()
+        sp.end()
+    assert _FakeAnnotation.log == [
+        ("enter", "serving.commit"), ("enter", "serving.commit.wait"),
+        ("exit", "serving.commit.wait"), ("exit", "serving.commit"),
+        ("enter", "batcher.flush"), ("exit", "batcher.flush")]
+    assert [e.get("parent") for e in rec.events] == \
+        ["serving.commit", None, None]
+
+
+def test_profiler_untouched_without_annotate(monkeypatch):
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("jax.profiler touched")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    assert obs.span("x") is obs.NULL_SPAN          # nothing installed
+    with obs.span("x") as sp:
+        assert sp is obs.NULL_SPAN
+    with obs.recording(obs.Recorder()) as rec:     # installed, no sink
+        with obs.span("y"):
+            pass
+    assert [e["name"] for e in rec.events] == ["y"]
 
 
 # -- disabled mode ----------------------------------------------------------
@@ -443,15 +525,16 @@ def test_view_by_name_aggregation(tmp_path, capsys):
         with obs.span("op.beta"):
             pass
     chrome = tmp_path / "t.json"
-    lines = tmp_path / "t.jsonl"
     obs.write_chrome_trace(rec, str(chrome))
-    obs.write_jsonl(rec, str(lines))
-    for path in (chrome, lines):
-        report = view.load(str(path))
-        agg = view.by_name(report["events"])
-        assert agg["op.alpha"]["count"] == 3
-        assert agg["op.beta"]["count"] == 1
-        assert agg["op.alpha"]["total_ms"] >= agg["op.alpha"]["mean_ms"]
-        assert view.main([str(path), "--by-name", "--top", "1"]) == 0
-        out = capsys.readouterr().out
-        assert "op.alpha" in out and "op.beta" not in out   # top-1
+    report = view.load(str(chrome))
+    agg = view.by_name(report["events"])
+    assert agg["op.alpha"]["count"] == 3
+    assert agg["op.alpha"]["cat"] == "q"
+    assert agg["op.beta"]["count"] == 1
+    assert agg["op.alpha"]["total_ms"] >= agg["op.alpha"]["mean_ms"]
+    assert view.main([str(chrome), "--by-name", "--top", "1"]) == 0
+    out = capsys.readouterr().out
+    assert "op.alpha" in out and "op.beta" not in out   # top-1
+    assert view.main([str(chrome), "--by-name"]) == 0
+    out = capsys.readouterr().out
+    assert "op.alpha" in out and "op.beta" in out

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import BACKENDS, engine, make_index
 from repro.data import points as gen
 from repro.serving import MicroBatcher, SpatialServer
@@ -224,6 +225,78 @@ def test_batcher_pow2_padding_hits_cached_plans():
         mb.submit_knn(QS[:s], K)
         mb.flush()
     assert engine.trace_count() == len(buckets)
+
+
+# ---------------------------------------------------------------------------
+# obs spans at the serving and engine boundaries
+# ---------------------------------------------------------------------------
+
+def _inside(child: dict, parent: dict) -> bool:
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_commit_phases_nest_inside_commit():
+    srv = _server("porth")
+    with obs.recording() as rec:
+        srv.delete(jnp.asarray(PTS[:64]))
+        srv.insert(jnp.asarray(BATCH))
+        srv.commit()
+        srv.commit()                      # nothing in flight: same phases
+    commits = [e for e in rec.events if e["name"] == "serving.commit"]
+    assert len(commits) == 2
+    phases = ("serving.commit.wait", "serving.commit.check",
+              "serving.commit.reclaim", "serving.commit.resolve")
+    for c in commits:
+        kids = [e for e in rec.events if e.get("parent") == "serving.commit"
+                and _inside(e, c)]
+        assert [e["name"] for e in kids] == list(phases)
+        assert sum(e["dur"] for e in kids) <= c["dur"]
+    assert len(srv.snapshot()) == N - 64 + BATCH.shape[0]
+
+
+def test_batcher_flush_covers_pack_call_and_split():
+    idx = make_index("spac-h", jnp.asarray(PTS), phi=PHI)
+    mb = MicroBatcher(idx, max_batch=1 << 30, max_delay_s=1e9)
+    with obs.recording() as rec:
+        kt = [mb.submit_knn(QS[i], K) for i in range(3)]
+        rt = [mb.submit_range_count(BOX_LO[i], BOX_HI[i]) for i in range(3)]
+        assert mb.flush() == 2            # one group per op
+    flushes = [e for e in rec.events if e["name"] == "batcher.flush"]
+    assert sorted(f["args"]["op"] for f in flushes) == ["knn",
+                                                        "range_count"]
+    for f in flushes:
+        kids = [e for e in rec.events if e.get("parent") == "batcher.flush"
+                and _inside(e, f)]
+        assert [e["name"] for e in kids] == ["batcher.pack",
+                                             "batcher.call",
+                                             "batcher.split"]
+    assert sum(e["name"] == "batcher.split" for e in rec.events) == 2
+    calls = [e for e in rec.events if e["name"] in ("engine.knn",
+                                                    "engine.range_count")]
+    assert sorted(e["name"] for e in calls) == ["engine.knn",
+                                                "engine.range_count"]
+    assert all(e["parent"] == "batcher.call" for e in calls)
+    assert all(t.done for t in kt + rt)
+
+
+def test_range_flush_without_escalation_syncs_once():
+    idx = make_index("spac-h", jnp.asarray(PTS), phi=PHI)
+    mb = MicroBatcher(idx, max_batch=1 << 30, max_delay_s=1e9)
+    mb.submit_range_count(BOX_LO, BOX_HI)
+    mb.flush()                            # converges the row bucket
+    with obs.recording() as rec:
+        t = mb.submit_range_count(BOX_LO, BOX_HI)
+        mb.flush()
+    assert rec.hist("engine.escalation_rounds").samples == [0.0]
+    (call,) = [e for e in rec.events if e["name"] == "engine.range_count"]
+    syncs = [e for e in rec.events if e["name"] == "engine.range_count.sync"]
+    assert len(syncs) == 1
+    assert syncs[0]["parent"] == "engine.range_count"
+    assert _inside(syncs[0], call)
+    lo, hi = BOX_LO[:, None, :], BOX_HI[:, None, :]
+    want = ((PTS[None] >= lo) & (PTS[None] <= hi)).all(-1).sum(-1)
+    np.testing.assert_array_equal(np.asarray(t.result()), want)
 
 
 # ---------------------------------------------------------------------------
