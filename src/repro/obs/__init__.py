@@ -62,7 +62,11 @@ Instrumented out of the box (counter/span names are stable API):
                                       phases:
 ``batcher.pack`` span                 concatenate, pad, to the device
 ``batcher.call`` span                 the engine call
-``batcher.split`` span                slicing each ticket's answer
+``batcher.split`` span                splitting out each ticket's answer
+``batcher.split.fused``               groups of one-row requests split
+                                      in one launch
+``batcher.split.sliced``              groups sliced ticket by ticket
+                                      (ragged requests, host outputs)
 ``batcher.coalesce_rows/pad_rows``    flush batch size / pad waste
 ``batcher.wait_s``                    request queue wait (submit->flush)
 ``batcher.flush.<reason>``            size|deadline|result|retarget|

@@ -13,9 +13,9 @@ The :class:`MicroBatcher` instead queues requests per plan signature
 ``(op, k, dim, dtype, impl)``, concatenates them, and **pads the
 coalesced batch to the next power of two** (replicating the final row —
 rows are independent under vmap, so padding never perturbs real
-answers). Batched answers are sliced back per request, and because every
-engine impl is exact and canonically (d2, id)-ordered, kNN and
-range-count answers **bit-match the answers the same requests would get
+answers). Batched answers are split back per request (below), and
+because every engine impl is exact and canonically (d2, id)-ordered, kNN
+and range-count answers **bit-match the answers the same requests would get
 dispatched alone** (asserted in tests/test_serving.py); range-list
 answers match in counts and id *sets*, but the padded id width is
 sized by the coalesced batch's largest output, so it can exceed the
@@ -32,6 +32,19 @@ next interaction point* — a ``submit``, an explicit ``poll()``, or a
 request waits forever). ``max_delay_s=0`` disables coalescing-by-wait:
 every submit flushes immediately. Trickle traffic that only polls
 ``Ticket.done`` should call ``poll()`` in its wait loop.
+
+Splitting a flushed group's answers (the ``batcher.split`` span) costs
+one device dispatch, not one per output per request, where it can: when
+every request of the group has one row and every output is a device
+array, all outputs are split into their rows by one jitted launch (one
+program per pow2 bucket and output signature, so it compiles with the
+group's query plans) and request i takes row i of each; pad rows are
+dropped. This is the ``batcher.split.fused`` counter. Groups with
+ragged requests, or with host (numpy) outputs, where a slice costs
+nothing, are sliced request by request (``batcher.split.sliced``).
+Either way each request gets the same device arrays, of the same
+shapes, dtypes and values, and the split reads nothing back from the
+device.
 
 Requests submitted as host (numpy) rows stay host-side until flush —
 one concatenate + one device transfer per coalesced batch — while
@@ -72,6 +85,14 @@ def _concat_pad(parts, rows: int):
     if pad:
         col = xp.concatenate([col, xp.repeat(col[-1:], pad, axis=0)])
     return col
+
+
+@jax.jit
+def _split_rows(*outs):
+    """Every row of every output as its own ``(1, ...)`` array, in one
+    launch: ``_split_rows(*outs)[j][i]`` is row i of ``outs[j]``."""
+    return tuple(tuple(o[i:i + 1] for i in range(o.shape[0]))
+                 for o in outs)
 
 
 class Ticket:
@@ -228,7 +249,7 @@ class MicroBatcher:
             with obs.span("batcher.call"):
                 if op == "knn":
                     # local indexes answer (d2, ids); distributed
-                    # snapshots answer (d2, points, valid) — slice
+                    # snapshots answer (d2, points, valid) — split
                     # whatever came back
                     outs = tuple(target.knn(cols[0], key[1], impl=key[4]))
                 elif op == "range_count":
@@ -237,8 +258,17 @@ class MicroBatcher:
                     ids, cnt = target.range_list(cols[0], cols[1])
                     outs = (ids, cnt)
             with obs.span("batcher.split"):
-                start = 0
-                for ticket, _, rows, _ts in reqs:
-                    sl = tuple(o[start: start + rows] for o in outs)
+                if (all(r[2] == 1 for r in reqs)
+                        and all(isinstance(o, jax.Array) for o in outs)):
+                    obs.count("batcher.split.fused")
+                    # request i takes row i of every output; pad rows go unused
+                    answers = zip(*_split_rows(*outs))
+                else:
+                    obs.count("batcher.split.sliced")
+                    answers, start = [], 0
+                    for _, _, rows, _ts in reqs:
+                        answers.append(tuple(o[start: start + rows]
+                                             for o in outs))
+                        start += rows
+                for (ticket, *_), sl in zip(reqs, answers):
                     ticket._resolve(sl if len(sl) > 1 else sl[0])
-                    start += rows

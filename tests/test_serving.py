@@ -17,6 +17,7 @@ the bounded version window, and a tiny end-to-end driver run.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,7 +25,7 @@ import pytest
 from repro import obs
 from repro.core import BACKENDS, engine, make_index
 from repro.data import points as gen
-from repro.serving import MicroBatcher, SpatialServer
+from repro.serving import MicroBatcher, SpatialServer, batcher
 from repro.serving.driver import DriverCfg, run_one
 
 PHI = 8
@@ -153,6 +154,87 @@ def test_batcher_bit_parity(kind):
         assert ((got >= 0).sum(-1) == np.asarray(want_cnt)).all()
 
 
+class _PointsTarget:
+    """Answers kNN the way distributed snapshots do: (d2, points,
+    valid), three outputs."""
+
+    def __init__(self, idx):
+        self.idx = idx
+
+    def knn(self, qpts, k, *, impl="auto"):
+        return self.idx.knn_points(qpts, k, impl=impl)
+
+
+def _assert_same_arrays(got, want):
+    got = got if isinstance(got, tuple) else (got,)
+    want = want if isinstance(want, tuple) else (want,)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert isinstance(g, jax.Array)
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("kind", sorted(BACKENDS))
+def test_batcher_one_row_groups_split_fused(kind):
+    """Groups of one-row requests are split in one launch: kNN,
+    range-count and a three-output kNN answer bit-match the same
+    requests dispatched alone, as device arrays of the same shapes."""
+    idx = make_index(kind, jnp.asarray(PTS), phi=PHI)
+    mb = MicroBatcher(idx, max_batch=1 << 30, max_delay_s=1e9)
+    mb3 = MicroBatcher(_PointsTarget(idx), max_batch=1 << 30,
+                       max_delay_s=1e9)
+    n = Q - 1                                 # 11 rows: 5 pad rows
+    with obs.recording() as rec:
+        knn_t = [mb.submit_knn(QS[i], K) for i in range(n)]
+        rng_t = [mb.submit_range_count(BOX_LO[i], BOX_HI[i])
+                 for i in range(n)]
+        pts_t = [mb3.submit_knn(QS[i], K) for i in range(n)]
+        assert mb.flush() == 2 and mb3.flush() == 1
+    assert rec.counters.get("batcher.split.fused") == 3
+    assert "batcher.split.sliced" not in rec.counters
+    for i in range(n):
+        _assert_same_arrays(knn_t[i].result(), idx.knn(QS[i:i + 1], K))
+        _assert_same_arrays(rng_t[i].result(),
+                            idx.range_count(BOX_LO[i:i + 1],
+                                            BOX_HI[i:i + 1]))
+        _assert_same_arrays(pts_t[i].result(),
+                            tuple(idx.knn_points(QS[i:i + 1], K)))
+
+
+class _HostTarget:
+    """Answers range counts as host arrays."""
+
+    def __init__(self, idx):
+        self.idx = idx
+
+    def range_count(self, lo, hi):
+        return np.asarray(self.idx.range_count(lo, hi))
+
+
+@pytest.mark.parametrize("case", ["ragged", "host"])
+def test_batcher_split_sliced_groups(case):
+    """Groups with multi-row requests, or with host outputs, are sliced
+    ticket by ticket; the answers are today's slices."""
+    idx = make_index("spac-h", jnp.asarray(PTS), phi=PHI)
+    spans = ([(0, 1), (1, 4), (4, 9), (9, Q)] if case == "ragged"
+             else [(i, i + 1) for i in range(Q)])
+    mb = MicroBatcher(idx if case == "ragged" else _HostTarget(idx),
+                      max_batch=1 << 30, max_delay_s=1e9)
+    with obs.recording() as rec:
+        ts = [mb.submit_range_count(BOX_LO[a:b], BOX_HI[a:b])
+              for a, b in spans]
+        mb.flush()
+    assert rec.counters.get("batcher.split.sliced") == 1
+    assert "batcher.split.fused" not in rec.counters
+    for (a, b), t in zip(spans, ts):
+        want = idx.range_count(BOX_LO[a:b], BOX_HI[a:b])
+        got = t.result()
+        assert isinstance(got, jax.Array if case == "ragged" else np.ndarray)
+        assert (got.shape, got.dtype) == (want.shape, want.dtype)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_batcher_admission_knobs():
     """max_batch triggers a flush on its own; max_delay_s=0 flushes on
     every submit (no coalescing-by-wait)."""
@@ -225,6 +307,24 @@ def test_batcher_pow2_padding_hits_cached_plans():
         mb.submit_knn(QS[:s], K)
         mb.flush()
     assert engine.trace_count() == len(buckets)
+
+
+def test_batcher_fused_split_compiles_once_per_bucket():
+    """Streams of one-row requests compile the split once per pow2
+    bucket, and a replay compiles nothing."""
+    idx = make_index("spac-h", jnp.asarray(PTS), phi=PHI)
+    mb = MicroBatcher(idx, max_batch=1 << 30, max_delay_s=1e9)
+    sizes = [1, 2, 3, 5, 7, 9, 12]
+    buckets = {1 << max(s - 1, 0).bit_length() for s in sizes}
+
+    batcher._split_rows.clear_cache()
+    for _ in range(2):                    # the replay compiles nothing
+        for s in sizes:
+            for i in range(s):
+                mb.submit_knn(QS[i], K)
+            mb.flush()
+        # (P, K) distances and ids split together, once per bucket
+        assert batcher._split_rows._cache_size() == len(buckets)
 
 
 # ---------------------------------------------------------------------------
