@@ -277,7 +277,6 @@ def main(argv=None):
     if args.rehearse and args.chips > 1:
         platform.stage(host_device_count=args.chips)
     import jax
-    import numpy as np
     from repro.data import points as gen
 
     devs = jax.devices()
@@ -300,9 +299,7 @@ def main(argv=None):
     # alone (9.0 GB) plus the retained versions leaves no margin on a
     # 16 GB chip (described-chip compile)
     kinds = ["spac-h", "porth"] if args.chips == 1 else ["spac-h"]
-    mesh = None
-    if args.chips > 1:
-        mesh = jax.sharding.Mesh(np.asarray(devs[:args.chips]), ("data",))
+    mesh = platform.device_mesh(args.chips) if args.chips > 1 else None
     phases = Phases(CompileClock())
     t0 = time.perf_counter()
     for kind in kinds:

@@ -42,7 +42,7 @@ def main():
           f"{time.time() - t0:.2f}s; size={len(idx)}")
 
     qs = gen.uniform(jax.random.PRNGKey(2), 64, 2)
-    d2, nbrs, ok = idx.knn(qs, 10)
+    d2, nbrs, ok = idx.knn_points(qs, 10)
     # exactness: compare one query against brute force
     allp = jnp.concatenate([pts, batch]).astype(jnp.float32)
     diff = allp - qs[0].astype(jnp.float32)

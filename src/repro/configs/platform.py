@@ -14,9 +14,11 @@ all of them:
   existing ``XLA_FLAGS`` (other flags survive; stale spellings of the
   same flag are replaced). Raises if the backend already initialized
   with a conflicting topology, and no-ops when the env already matches.
-* :func:`simulate_mesh` — the ``--mesh N`` entry point: a 1-D device
-  mesh over the first ``n`` devices. On an accelerator host those are
-  the real chips; on the CPU it stages ``n`` forced host devices first.
+* :func:`device_mesh` — a 1-D device mesh over the first ``n``
+  devices JAX sees (what ``make_index(..., mesh=n)`` builds).
+* :func:`simulate_mesh` — the ``--mesh N`` entry point: stages ``n``
+  forced host devices, then builds the :func:`device_mesh` (on an
+  accelerator host over the real chips, on the CPU over those).
   An 8-device CPU mesh exercises the full shard_map exchange
   (all_to_all/all_gather/psum routing) on a laptop or CI runner; see
   tests/helpers.py ``run_on_simulated_mesh`` for the subprocess fixture
@@ -141,27 +143,35 @@ def use_compile_cache() -> str:
     return path
 
 
-def simulate_mesh(n: int, axis_names: tuple[str, ...] = ("data",)):
-    """A 1-D ``Mesh`` over the first ``n`` devices of the process.
+def device_mesh(n: int, axis_names: tuple[str, ...] = ("data",)):
+    """A 1-D ``Mesh`` over the first ``n`` devices JAX sees: on a TPU
+    host its chips, on the CPU the staged host devices. Raises when
+    fewer than ``n`` exist; it never builds a smaller mesh.
 
-    Where an accelerator is present (a TPU host) those are its chips,
-    and the staged host device count is ignored by the accelerator
-    backend. On the CPU, ``n`` forced host devices are staged first
-    (CI's simulated pod). Raises when fewer than ``n`` devices exist.
-
-    Must be the first jax-touching call of the process (the subprocess
-    fixture in tests/helpers.py guarantees this for tests; the serving
-    driver's ``--mesh N`` flag calls it before building anything)."""
-    stage(host_device_count=n)
+    ``make_index(..., mesh=n)`` builds its mesh here, so a
+    configuration file can name a chip count."""
     import jax
     import numpy as np
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(
-            f"simulate_mesh({n}): only {len(devs)} "
-            f"{devs[0].platform} device(s) visible. On the CPU the "
-            f"forced host device count was staged after jax "
-            f"initialized: call simulate_mesh (or stage) before any "
-            f"jax.devices()/array op, or use "
-            f"tests/helpers.py:run_on_simulated_mesh.")
+            f"a mesh of {n} devices was asked for, but JAX sees only "
+            f"{len(devs)} {devs[0].platform} device(s). On the CPU, "
+            f"stage the host device count before jax initializes "
+            f"(simulate_mesh, or tests/helpers.py:run_on_simulated_mesh).")
     return jax.sharding.Mesh(np.asarray(devs[:n]), axis_names)
+
+
+def simulate_mesh(n: int, axis_names: tuple[str, ...] = ("data",)):
+    """:func:`device_mesh` after staging ``n`` forced host devices.
+
+    Where an accelerator is present (a TPU host) the mesh is over its
+    chips, and the staged host device count is ignored by the
+    accelerator backend. On the CPU the ``n`` staged host devices are
+    CI's simulated pod.
+
+    Must be the first jax-touching call of the process (the subprocess
+    fixture in tests/helpers.py guarantees this for tests; the serving
+    driver's ``--mesh N`` flag calls it before building anything)."""
+    stage(host_device_count=n)
+    return device_mesh(n, axis_names)

@@ -20,9 +20,12 @@ the multi-node analogue of the paper's shared-memory design:
     tree over its key range; batch insert/delete are the paper's
     algorithms unchanged.
   * queries — kNN fans out (queries replicated), each shard answers
-    exactly from its range, and a top-k merge over an all_gather
-    combines candidates; exact because shards partition the point set.
-    Range-count is a local count + psum.
+    exactly from its range, and a top-k merge over an all_gather of
+    each shard's (d2, global id) combines candidates; exact because
+    shards partition the point set. A global id is the shard's offset
+    in the shard-major flattened points plus its local row*C+slot, so
+    kNN returns the same (d2, ids) as a local index. Range-count is a
+    local count + psum.
 
 Every collective program here — updates and queries — is built by an
 ``lru_cache`` closure factory returning ``jax.jit(shard_map(local))``:
@@ -242,9 +245,15 @@ def _update_closure(mesh, axis: str, n_shards: int, cap: int, kind: str,
                     else spac.delete_impl(tree, rp, rm))
         return _stack(tree), dropped
 
-    return jax.jit(_smap(
-        local, mesh, in_specs=(P(axis), P(axis), P(axis), P()),
-        out_specs=(P(axis), P())))
+    smapped = _smap(local, mesh, in_specs=(P(axis), P(axis), P(axis), P()),
+                    out_specs=(P(axis), P()))
+
+    def run(tree, p, k, splitters):
+        # named ``run`` like every other closure of the index and the
+        # engine, so a profiler trace names the program ``jit_run``
+        return smapped(tree, p, k, splitters)
+
+    return jax.jit(run)
 
 
 # Query closures are shard_map programs like the updates: each shard
@@ -273,18 +282,25 @@ def _knn_closure(mesh, axis: str, k: int, impl: str, kernel: str,
             flat_pts, flat_ok = Q.flatten_view(view)
             d2, ids = knn_ops.knn_bruteforce_impl(
                 q, flat_pts, flat_ok, k=k, impl=kernel)
-        pts = Q.gather_points(view, ids)
-        d2 = jnp.where(ids >= 0, d2, BIG)
-        # (Q, S*k) candidates, shard-major: top_k's lowest-index tie
-        # break then prefers the lower shard
+        # the shard's hits in (d2, local id) order, BIG-padded
+        d2, ids = _engine.canonical_knn(jnp.where(ids >= 0, d2, BIG), ids)
+        # (Q, S*k) candidates, shard-major: top_k takes the lower index
+        # among equal distances, so ties go to the lower shard, then to
+        # the lower local id: the lowest global id
         cat_d2 = jax.lax.all_gather(d2, axis, axis=1, tiled=True)
-        cat_pts = jax.lax.all_gather(pts, axis, axis=1, tiled=True)
         neg, sel = jax.lax.top_k(-cat_d2, k)
-        best = jnp.take_along_axis(cat_pts, sel[..., None], axis=1)
-        return -neg, best, (-neg) < BIG
+        d2 = -neg
+        # global flat id: the shard's offset in extract_points()'s
+        # shard-major (S*R*C, dim) layout plus its local row*C+slot
+        rows, cols = view.pts.shape[:2]
+        gid = jnp.where(ids >= 0,
+                        jax.lax.axis_index(axis) * (rows * cols) + ids, -1)
+        cat_gid = jax.lax.all_gather(gid, axis, axis=1, tiled=True)
+        best = jnp.take_along_axis(cat_gid, sel, axis=1)
+        return d2, jnp.where(d2 < BIG, best, -1)
 
     smapped = _smap(local, mesh, in_specs=(P(axis), P()),
-                    out_specs=(P(), P(), P()))
+                    out_specs=(P(), P()))
 
     def run(tree, q):
         # trace-time counter: same contract as the engine's local query
@@ -365,6 +381,7 @@ def _update(index: DistIndex, pts, mask, mesh, op: str, slack: float):
     m = pts.shape[0]
     cap = int((m // n_shards) * slack / n_shards) + 8
     R = index.tree.pts.shape[-3]
+    obs.count("dist.update.routed", n_shards * n_shards * cap)
     fn = _update_closure(mesh, axis, n_shards, cap, index.kind, op,
                          min(64, R), index.ckey)
     tree, dropped = fn(index.tree, pts, mask, index.splitters)
@@ -385,18 +402,30 @@ def delete(index: DistIndex, pts, mesh, mask=None, *, slack: float = 2.0):
 def knn(index: DistIndex, qpts, k: int, mesh, chunk: int = 8,
         impl: str = "frontier", kernel: str = "auto"):
     """Exact distributed kNN. qpts: (Q, dim) replicated. Returns
-    (d2 (Q, k) ascending, points (Q, k, dim), valid (Q, k)).
+    (d2 (Q, k) ascending, ids (Q, k)), the same result type as a local
+    index's kNN: ``ids`` are global flat ids, ``s*R*C + row*C + slot``
+    on shard ``s``, which index the shard-major flattened points of
+    :meth:`repro.core.index.DistributedIndex.extract_points`; -1 pads.
+    Equal distances go to the lowest id.
 
     ``impl="frontier"`` runs the chunked frontier traversal per shard;
     ``impl="pallas-frontier"`` the fused frontier kernel;
     ``impl="flat"`` the brute-force scan (``kernel`` picks the kernel
     flavor: auto/pallas/pallas-interpret/ref)."""
+    S, R, C = index.tree.pts.shape[:3]
+    if S * R * C > 2**31:
+        raise ValueError(
+            f"{S} shards x {R} rows x {C} slots = {S * R * C} slots: "
+            f"global kNN ids are int32 and would wrap past 2**31 - 1")
+    obs.count("dist.knn.calls")
+    obs.count("dist.knn.candidates", qpts.shape[0] * S * int(k))
     fn = _knn_closure(mesh, index.axis, int(k), impl, kernel, int(chunk))
     return fn(index.tree, qpts)
 
 
 def range_count(index: DistIndex, lo, hi, mesh, max_rows: int = 128):
     """Exact distributed range-count: per-shard count + global sum."""
+    obs.count("dist.range.calls")
     fn = _range_count_closure(mesh, index.axis, int(max_rows))
     return fn(index.tree, lo, hi)
 

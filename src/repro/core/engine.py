@@ -37,7 +37,9 @@ silently violated. The :class:`QueryEngine` owns those knobs instead:
   unjitted ``*_impl`` spellings inside shard_map (required — see the
   ROADMAP miscompile note), the shard-merge step takes the top-k of
   per-shard top-k (kNN) or the psum of per-shard counts (range), and
-  the same bucket escalation wraps the whole exchange.
+  the same bucket escalation wraps the whole exchange. Distributed kNN
+  ids are global flat ids into the sharded index's flattened points,
+  so a local and a sharded index return one kNN result type.
 
 kNN results are *canonical*: each query's k hits are sorted by
 ``(d2, id)``, so any two exact impls return bit-identical output on
@@ -315,28 +317,26 @@ class QueryEngine:
     # -- distributed queries (shard-merge step) ----------------------------
 
     def knn_dist(self, index, qpts, k: int, mesh, impl: str = "auto"):
-        """Exact distributed kNN -> (d2, neighbor points, valid): each
-        shard answers locally (frontier or flat scan — unjitted inside
-        shard_map), then the merge takes the top-k of per-shard top-k."""
+        """Exact distributed kNN -> (d2, global flat ids) like
+        :meth:`knn`: each shard answers locally (frontier or flat scan —
+        unjitted inside shard_map), then the merge takes the top-k of
+        per-shard top-k."""
         from . import distributed as D
-        with obs.span("engine.knn"):
+        with obs.span("engine.knn", shards=mesh.shape[index.axis]):
             rows, cols = index.tree.pts.shape[-3], index.tree.pts.shape[-2]
             route, param = self.plan_knn(rows, cols, impl)
             obs.count("engine.plan_request")
             obs.count(f"engine.route.{route}")
-            if route == "frontier":
-                return D.knn(index, qpts, k, mesh, chunk=param)
-            if route == "pallas-frontier":
-                return D.knn(index, qpts, k, mesh, impl="pallas-frontier",
-                             kernel=param)
-            return D.knn(index, qpts, k, mesh, impl="flat", kernel=param)
+            kw = ({"chunk": param} if route == "frontier"
+                  else {"impl": route, "kernel": param})
+            return D.knn(index, qpts, k, mesh, **kw)
 
     def range_count_dist(self, index, lo, hi, mesh):
         """Exact distributed range count -> counts (Q,): per-shard
         count + psum, re-run at escalated row buckets until no shard
         truncates."""
         from . import distributed as D
-        with obs.span("engine.range_count"):
+        with obs.span("engine.range_count", shards=mesh.shape[index.axis]):
             rows = index.tree.pts.shape[-3]
             key = ("range_count_dist", lo.shape[0], lo.shape[-1],
                    str(lo.dtype))

@@ -576,7 +576,9 @@ def make_index(kind: str, points, mask=None, *, phi: int = 32,
     keyword params. With ``mesh=`` the index is built key-range-partitioned
     over the mesh's devices and a :class:`DistributedIndex` is returned
     (mesh-capable kinds: the spac family routes by curve code, porth by
-    sieve prefix key).
+    sieve prefix key). ``mesh`` is a ``jax.sharding.Mesh`` or a device
+    count ``n``: a 1-D mesh over the first ``n`` devices JAX sees
+    (:func:`repro.configs.platform.device_mesh`; fewer raise).
     """
     if mesh is not None:
         if donate:
@@ -633,9 +635,11 @@ def make_index(kind: str, points, mask=None, *, phi: int = 32,
 
 class DistributedIndex:
     """The same surface over an SFC-range-partitioned index on a device
-    mesh (:mod:`repro.core.distributed`). kNN returns neighbor coordinates
-    instead of flat slot ids (ids are shard-local and meaningless
-    globally); ``range_list`` is not offered distributed."""
+    mesh (:mod:`repro.core.distributed`). kNN returns the local facade's
+    result type, (d2, flat ids): an id is global, ``s*R*C + row*C +
+    slot`` on shard ``s``, and indexes the shard-major flattened points
+    of :meth:`extract_points`; ``knn_points`` returns coordinates.
+    ``range_list`` is not offered distributed."""
 
     def __init__(self, kind: str, index, mesh, *, phi: int,
                  slack: float = 2.0, build_kw: dict | None = None,
@@ -656,6 +660,9 @@ class DistributedIndex:
               capacity_points: int | None = None, slack: float = 2.0,
               n_samples: int = 256, axis: str = "data", **params):
         from . import distributed as D
+        if isinstance(mesh, int):
+            from ..configs import platform
+            mesh = platform.device_mesh(mesh, (axis,))
         backend = get_backend(kind)
         pts = jnp.asarray(points)
         if kind == "porth":
@@ -753,8 +760,8 @@ class DistributedIndex:
 
     def shard_sizes(self):
         """Per-shard live point counts, shape (n_shards,) — metadata
-        arithmetic on the stacked leaves, cheap enough for per-shard
-        obs gauges in the serving driver."""
+        arithmetic on the stacked leaves, cheap enough for the per-shard
+        obs gauges that ``SpatialServer.commit`` attaches."""
         from . import distributed as D
         return D.shard_sizes(self._index)
 
@@ -865,13 +872,20 @@ class DistributedIndex:
         return self._engine
 
     def knn(self, qpts, k: int, *, impl: str = "auto"):
-        """Exact distributed kNN -> (d2, neighbor points, valid): the
-        engine routes each shard's local query (frontier vs flat scan)
-        and merges via top-k of per-shard top-k."""
+        """Exact distributed kNN -> (d2 (Q, k) ascending, global flat ids
+        (Q, k), -1 padded): the engine routes each shard's local query
+        (frontier vs flat scan) and merges via top-k of per-shard top-k;
+        equal distances go to the lowest id."""
         return self._engine.knn_dist(self._index, jnp.asarray(qpts), k,
                                      self.mesh, impl=impl)
 
-    knn_points = knn
+    def knn_points(self, qpts, k: int, *, impl: str = "auto"):
+        """kNN returning coordinates: (d2, neighbor points, valid), the
+        points gathered on the device at :meth:`knn`'s global ids."""
+        d2, ids = self.knn(qpts, k, impl=impl)
+        ok = ids >= 0
+        pts = self.extract_points()[0][jnp.maximum(ids, 0)]
+        return d2, jnp.where(ok[..., None], pts, 0), ok
 
     def range_count(self, lo, hi):
         """Exact distributed range count -> counts (Q,): per-shard
@@ -884,6 +898,8 @@ class DistributedIndex:
         return self
 
     def extract_points(self):
+        """(points (S*R*C, dim), valid (S*R*C,)), shard-major: the
+        layout :meth:`knn`'s global ids index."""
         t = self._index.tree
         dim = t.pts.shape[-1]
         ok = (t.valid & t.active[..., None]).reshape(-1)

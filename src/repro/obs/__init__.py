@@ -40,9 +40,18 @@ Instrumented out of the box (counter/span names are stable API):
 ``engine.route.frontier|flat``        kNN impl routing decisions
 ``engine.escalation_rounds``          pow2 buffer escalations per call
 ``engine.knn`` span                   one kNN call: plan lookup and
-                                      dispatch
+                                      dispatch (``shards`` attribute
+                                      on a mesh)
 ``engine.range_count`` span           one range-count call: plan,
                                       dispatch, escalation rounds
+                                      (``shards`` on a mesh)
+``dist.knn.calls``                    distributed kNN launches
+``dist.knn.candidates``               rows the kNN merge gathers:
+                                      Q x shards x k per launch
+``dist.range.calls``                  distributed range-count launches
+``dist.update.routed``                rows of the all_to_all routing
+                                      slab per update: shards^2 x the
+                                      per-pair capacity
 ``engine.range_count.sync`` span      the truncation read of each round
                                       (a host wait on the device)
 ``engine.range_list.sync`` span       range-list's truncation and
@@ -74,6 +83,10 @@ Instrumented out of the box (counter/span names are stable API):
 ``server.mem.live_bytes``             head-version buffer bytes (gauge)
 ``server.mem.window_bytes``           retained-window bytes (gauge)
 ``server.mem.evicted_bytes``          bytes freed by window eviction
+``server.shard<i>.live_points``       live points on shard i at the
+                                      last commit (gauge, mesh only)
+``server.shard.max_over_mean``        largest shard's live points over
+                                      the mean (gauge, mesh only)
 ``plan.cost.<sig>.flops|bytes``       captured per-plan cost model
                                       (``Recorder(capture_costs=True)``)
 ``backend.mem.d<id>.bytes_in_use``    allocator stats, resolve()-only
@@ -104,7 +117,8 @@ from .record import NULL_SPAN, Hist, NullSpan, Recorder, Span, pow2_bucket
 __all__ = [
     "Recorder", "Span", "NullSpan", "NULL_SPAN", "Hist", "pow2_bucket",
     "install", "uninstall", "recording", "enabled", "recorder",
-    "span", "count", "gauge", "observe", "defer", "resolve",
+    "span", "count", "gauge", "observe", "defer", "defer_gauge",
+    "resolve",
     "chrome_trace", "write_chrome_trace",
     "costs", "tree_bytes", "fmt_bytes",
 ]
@@ -179,6 +193,14 @@ def defer(name: str, value) -> None:
     rec = _STATE["rec"]
     if rec is not None:
         rec.add_deferred(name, value)
+
+
+def defer_gauge(name: str, value) -> None:
+    """Attach an in-flight device scalar to gauge ``name``; set at the
+    next :func:`resolve` (no host read here)."""
+    rec = _STATE["rec"]
+    if rec is not None:
+        rec.add_deferred_gauge(name, value)
 
 
 def resolve() -> int:

@@ -11,8 +11,9 @@ compiles or dispatches.
 Two invariants this module owns (see ROADMAP "Observability"):
 
 * **No host sync off the barrier.** Instrumented code may *attach*
-  in-flight device values to a span (:meth:`Span.defer`) or to a named
-  counter (:meth:`Recorder.add_deferred`) — both are list appends. The
+  in-flight device values to a span (:meth:`Span.defer`), to a named
+  counter (:meth:`Recorder.add_deferred`) or to a gauge
+  (:meth:`Recorder.add_deferred_gauge`) — all list appends. The
   host read happens only in :meth:`Recorder.resolve`, which callers
   invoke at an existing barrier (``SpatialServer.commit``, report
   time). This mirrors the serving runtime's sticky-``overflowed``
@@ -352,6 +353,12 @@ class Recorder:
         with self._lock:
             self._pending.append((name, None, value))
 
+    def add_deferred_gauge(self, name: str, value) -> None:
+        """Attach an in-flight device scalar to gauge ``name``; it is
+        set (via one host read) at the next ``resolve()``."""
+        with self._lock:
+            self._pending.append((name, "gauge", value))
+
     @property
     def pending(self) -> int:
         """Deferred device reads not yet resolved."""
@@ -393,7 +400,9 @@ class Recorder:
         for target, key, value in pending:
             value = jax.block_until_ready(value)
             now = self.clock() - self.t0
-            if isinstance(target, str):           # deferred counter
+            if isinstance(target, str) and key == "gauge":
+                self.gauge(target, float(value))  # deferred gauge
+            elif isinstance(target, str):         # deferred counter
                 self.count(target, float(value))
             else:                                 # span attribute
                 try:

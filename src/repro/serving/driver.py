@@ -195,10 +195,9 @@ def run_one(kind: str, scenario: str, cfg: DriverCfg,
     if mesh is not None:
         # per-shard balance report: live points per shard from the
         # key-range routing, plus the cumulative routing-drop counter
-        # (0 after checked updates / commit — drops trigger replay)
+        # (0 after checked updates / commit — drops trigger replay);
+        # the server's commit keeps the same as obs gauges
         sizes = np.asarray(srv.head_index.shard_sizes())
-        for i, s in enumerate(sizes.tolist()):
-            obs.gauge(f"server.shard{i}.live_points", int(s))
         out["distributed"] = {
             "n_shards": int(sizes.shape[0]),
             "shard_points": [int(s) for s in sizes.tolist()],

@@ -302,10 +302,24 @@ class SpatialServer:
                 self._rebase_memory(head)
             self.stats["commits"] += 1
             # commit is THE barrier: deferred obs device reads (span
-            # attachments, deferred counters) resolve here for free
+            # attachments, deferred counters and gauges) resolve here
+            # for free
             with obs.span("serving.commit.resolve"):
+                if self._distributed and obs.enabled():
+                    self._defer_shard_gauges(head)
                 obs.resolve()
             return self._head
+
+    @staticmethod
+    def _defer_shard_gauges(head: DistributedIndex) -> None:
+        """Per-shard balance of a mesh-sharded head, as gauges read at
+        the next resolve: live points per shard and the largest over
+        the mean (the straggler signal of key-range routing)."""
+        sizes = head.shard_sizes()
+        for i in range(sizes.shape[0]):
+            obs.defer_gauge(f"server.shard{i}.live_points", sizes[i])
+        obs.defer_gauge("server.shard.max_over_mean",
+                        jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1))
 
     def _recover(self) -> SpatialIndex:
         """Replay the op log from the last good version through the

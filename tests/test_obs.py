@@ -108,6 +108,20 @@ def test_deferred_values_resolve_only_at_barrier():
     assert rec.counters["points"] == 5.0
 
 
+def test_deferred_gauges_are_set_at_the_barrier():
+    rec = obs.Recorder()
+    with obs.recording(rec):
+        obs.defer_gauge("shard0.live", jnp.asarray([4, 6]).max())
+        obs.defer_gauge("shard0.live", jnp.asarray(3))
+        obs.defer("rows", jnp.asarray(2))
+        assert "shard0.live" not in rec.gauges and rec.pending == 3
+        assert obs.resolve() == 3
+    assert rec.gauges["shard0.live"] == {"value": 3.0, "max": 6.0, "n": 2}
+    assert rec.counters["rows"] == 2.0
+    obs.defer_gauge("off", jnp.asarray(1))      # no recorder: nothing kept
+    assert rec.pending == 0 and "off" not in rec.gauges
+
+
 def test_server_commit_is_the_obs_barrier():
     pts = _pts(256)
     with obs.recording() as rec:

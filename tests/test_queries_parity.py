@@ -418,7 +418,7 @@ qs = gen.uniform(jax.random.PRNGKey(2), 16, 2)
 # brute force
 allp = np.asarray(pts, np.float64)
 for impl in ("auto", "frontier", "pallas-frontier"):
-    d2, bp, ok = idx.knn(qs, 5, impl=impl)
+    d2, bp, ok = idx.knn_points(qs, 5, impl=impl)
     for i in range(16):
         bf = np.sort(((allp - np.asarray(qs[i], np.float64)) ** 2
                       ).sum(-1))[:5]

@@ -155,8 +155,8 @@ def test_batcher_bit_parity(kind):
 
 
 class _PointsTarget:
-    """Answers kNN the way distributed snapshots do: (d2, points,
-    valid), three outputs."""
+    """Answers kNN with coordinates, (d2, points, valid): a target whose
+    answer has three outputs."""
 
     def __init__(self, idx):
         self.idx = idx

@@ -52,15 +52,15 @@ for kind in ("spac-h", "spac-z", "porth"):
     # come from the pre-update version (snapshot isolation)
     srv.insert(newp)
     srv.delete(pts[:256])
+    flat, valid = map(np.asarray, snap.index.extract_points())
     for i, t in enumerate(knn_tk):
-        d2, bp, ok = t.result()
-        d2, bp = np.asarray(d2)[0], np.asarray(bp)[0]
+        d2, ids = (np.asarray(x)[0] for x in t.result())
         np.testing.assert_array_equal(d2, solo_d2[i]), (kind, i)
-        # the returned neighbor coordinates reproduce the distances
-        diff = bp.astype(np.float32) - qs[i].astype(np.float32)
-        re_d2 = (diff * diff).sum(-1)
-        assert np.allclose(re_d2[np.asarray(ok)[0]],
-                           d2[np.asarray(ok)[0]]), (kind, i)
+        # the global ids index the snapshot's flattened points, whose
+        # coordinates reproduce the distances
+        assert (ids >= 0).all() and valid[ids].all(), (kind, i)
+        diff = flat[ids].astype(np.float32) - qs[i].astype(np.float32)
+        assert np.allclose((diff * diff).sum(-1), d2), (kind, i)
     for i, t in enumerate(cnt_tk):
         assert int(np.asarray(t.result())[0]) == int(solo_cnt[i]), (kind, i)
     srv.commit()
@@ -100,7 +100,7 @@ assert srv.stats["recoveries"] >= 1, srv.stats
 assert int(srv.head_index.dropped) == 0
 # post-recovery head serves exact answers
 qs = np.asarray(gen.uniform(jax.random.PRNGKey(2), 4, 2))
-d2, bp, ok = srv.snapshot().knn(qs, 5)
+d2, bp, ok = srv.snapshot().knn_points(qs, 5)
 assert np.asarray(ok).all()
 print("REPLAY_OK")
 """
