@@ -318,10 +318,10 @@ def _range_count_closure(mesh, axis: str, max_rows: int):
     obs.count("dist.plan_miss")
 
     def local(tree, lo, hi):
-        cnt, trunc = Q.range_count_impl(_unstack(tree).view(), lo, hi,
-                                        max_rows)
+        cnt, n_overlap = Q.range_count_impl(_unstack(tree).view(), lo, hi,
+                                            max_rows)
         return (jax.lax.psum(cnt, axis),
-                jax.lax.psum(trunc.astype(jnp.int32), axis) > 0)
+                jax.lax.pmax(jnp.max(n_overlap, initial=0), axis))
 
     smapped = _smap(local, mesh, in_specs=(P(axis), P(), P()),
                     out_specs=(P(), P()))
@@ -424,7 +424,9 @@ def knn(index: DistIndex, qpts, k: int, mesh, chunk: int = 8,
 
 
 def range_count(index: DistIndex, lo, hi, mesh, max_rows: int = 128):
-    """Exact distributed range-count: per-shard count + global sum."""
+    """Distributed range-count: per-shard count + global sum. Returns
+    (counts (Q,), the largest count of rows overlapping one box on one
+    shard); the counts are exact where that is <= ``max_rows``."""
     obs.count("dist.range.calls")
     fn = _range_count_closure(mesh, index.axis, int(max_rows))
     return fn(index.tree, lo, hi)

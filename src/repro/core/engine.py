@@ -152,7 +152,8 @@ def _range_count_closure(q: int, dim: int, dtype: str, max_rows: int):
     def run(view, lo, hi):
         _STATS["traces"] += 1
         obs.count("engine.trace")
-        return queries.range_count_impl(view, lo, hi, max_rows)
+        cnt, n_overlap = queries.range_count_impl(view, lo, hi, max_rows)
+        return cnt, jnp.max(n_overlap, initial=0)
     return jax.jit(run)
 
 
@@ -242,33 +243,47 @@ class QueryEngine:
                 f".v{rows}x{cols}")
             return fn(view, qpts)
 
+    def _range_rounds(self, key, rows: int, launch):
+        """Run ``launch(max_rows) -> (counts, largest overlap)`` at
+        power-of-two row buckets until the bucket holds every box's
+        overlapping rows; the converged bucket is remembered under
+        ``key``. The one host read per round is the largest overlap."""
+        max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
+                       _pow2(rows))
+        obs.count("engine.plan_request")
+        rounds = 0
+        while True:
+            cnt, overlap = launch(max_rows)
+            with obs.span("engine.range_count.sync"):
+                overlap = int(overlap)
+            obs.observe("engine.range_count.overlap_rows", overlap)
+            if max_rows >= rows or overlap <= max_rows:
+                self._buckets[key] = max_rows
+                obs.observe("engine.escalation_rounds", rounds)
+                return cnt
+            rounds += 1
+            obs.count("engine.escalation")
+            # the overlap is exact even when truncated, so jump
+            # straight to the bucket that holds it
+            max_rows = min(_pow2(overlap), _pow2(rows))
+
     def range_count(self, view: queries.LeafView, lo, hi):
         """Exact batched range count -> counts (Q,). Escalates the row
         buffer through power-of-two buckets until nothing truncates."""
+        rows, cols, _ = view.pts.shape
+
+        def launch(max_rows):
+            fn = _range_count_closure(lo.shape[0], lo.shape[-1],
+                                      str(lo.dtype), max_rows)
+            obs.costs.capture(
+                fn, (view, lo, hi),
+                f"range_count.q{lo.shape[0]}.r{max_rows}.v{rows}x{cols}")
+            return fn(view, lo, hi)
+
         with obs.span("engine.range_count"):
-            rows = view.pts.shape[0]
-            key = ("range_count", lo.shape[0], lo.shape[-1], str(lo.dtype))
-            max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
-                           _pow2(rows))
-            obs.count("engine.plan_request")
-            rounds = 0
-            while True:
-                fn = _range_count_closure(lo.shape[0], lo.shape[-1],
-                                          str(lo.dtype), max_rows)
-                obs.costs.capture(
-                    fn, (view, lo, hi),
-                    f"range_count.q{lo.shape[0]}.r{max_rows}"
-                    f".v{rows}x{view.pts.shape[1]}")
-                cnt, trunc = fn(view, lo, hi)
-                with obs.span("engine.range_count.sync"):
-                    done = max_rows >= rows or not bool(jnp.any(trunc))
-                if done:
-                    self._buckets[key] = max_rows
-                    obs.observe("engine.escalation_rounds", rounds)
-                    return cnt
-                rounds += 1
-                obs.count("engine.escalation")
-                max_rows = min(2 * max_rows, _pow2(rows))
+            return self._range_rounds(
+                ("range_count", lo.shape[0], lo.shape[-1], str(lo.dtype)),
+                rows, launch)
 
     def range_list(self, view: queries.LeafView, lo, hi):
         """Exact batched range report -> (ids (Q, cap) flat row*C+slot
@@ -337,22 +352,9 @@ class QueryEngine:
         truncates."""
         from . import distributed as D
         with obs.span("engine.range_count", shards=mesh.shape[index.axis]):
-            rows = index.tree.pts.shape[-3]
-            key = ("range_count_dist", lo.shape[0], lo.shape[-1],
-                   str(lo.dtype))
-            max_rows = min(_pow2(self._buckets.get(key, self.start_rows)),
-                           _pow2(rows))
-            obs.count("engine.plan_request")
-            rounds = 0
-            while True:
-                cnt, trunc = D.range_count(index, lo, hi, mesh,
-                                           max_rows=max_rows)
-                with obs.span("engine.range_count.sync"):
-                    done = max_rows >= rows or not bool(jnp.any(trunc))
-                if done:
-                    self._buckets[key] = max_rows
-                    obs.observe("engine.escalation_rounds", rounds)
-                    return cnt
-                rounds += 1
-                obs.count("engine.escalation")
-                max_rows = min(2 * max_rows, _pow2(rows))
+            return self._range_rounds(
+                ("range_count_dist", lo.shape[0], lo.shape[-1],
+                 str(lo.dtype)),
+                index.tree.pts.shape[-3],
+                lambda max_rows: D.range_count(index, lo, hi, mesh,
+                                               max_rows=max_rows))

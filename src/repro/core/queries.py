@@ -8,7 +8,8 @@ best distance. This is exact (same pruning argument as best-first search) and
 fully vectorized over queries via ``vmap``.
 
 Range queries gather candidate rows whose bbox overlaps the query box (fixed
-capacity ``max_rows``, with a truncation flag so callers can size it).
+capacity ``max_rows``; each box's count of overlapping rows tells callers
+whether it sufficed and how to size it).
 
 The engine only needs the "leaf directory view" every index exposes:
     pts (R, C, D), valid (R, C), active (R,), bbox_lo/hi (R, D)
@@ -127,37 +128,75 @@ def _boxes_overlap(lo_a, hi_a, lo_b, hi_b):
                    axis=-1)
 
 
+# block width of the two-level selection below: one TPU lane row
+SELECT_BLOCK = 128
+
+
+def _first_true(mask, m: int):
+    """First ``min(m, L)`` entries of a stable ``argsort(~mask)`` over
+    the (L,) ``mask``: the True positions in order, then the False ones.
+
+    Two levels, so nothing sorts or scatters over L: count each block
+    of ``SELECT_BLOCK`` entries, find each output slot's block by a
+    binary search on the blocks' running counts, then its entry by a
+    running count over that one block's bits. Slot j < n_true is the
+    j-th True entry; slot j >= n_true the (j - n_true)-th False one (a
+    block holds B minus its count of those). The padding to whole
+    blocks is False and sorts after every real entry, so no slot
+    reaches it."""
+    L = mask.shape[0]
+    nb = -(-L // SELECT_BLOCK)
+    bits = jnp.pad(mask, (0, nb * SELECT_BLOCK - L)).reshape(
+        nb, SELECT_BLOCK)
+    per_block = jnp.sum(bits, axis=1, dtype=jnp.int32)
+    true_end = jnp.cumsum(per_block)               # inclusive
+    false_end = (jnp.arange(1, nb + 1, dtype=jnp.int32) * SELECT_BLOCK
+                 - true_end)
+    n_true = true_end[-1]
+    j = jnp.arange(min(int(m), L), dtype=jnp.int32)
+    want = j < n_true
+    t = jnp.where(want, j, j - n_true)            # rank among its kind
+    blk = jnp.where(want,
+                    jnp.searchsorted(true_end, t, side="right"),
+                    jnp.searchsorted(false_end, t, side="right")
+                    ).astype(jnp.int32)
+    true_before = true_end[blk] - per_block[blk]
+    rank = t - jnp.where(want, true_before,
+                         blk * SELECT_BLOCK - true_before)
+    kind = bits[blk] == want[:, None]              # (slots, B)
+    seen = jnp.cumsum(kind, axis=1, dtype=jnp.int32)
+    pos = jnp.sum(seen <= rank[:, None], axis=1, dtype=jnp.int32)
+    return blk * SELECT_BLOCK + pos
+
+
 def _range_rows(view: LeafView, lo, hi, max_rows: int):
+    """Candidate rows of one query box: the first ``min(max_rows, R)``
+    rows whose bbox overlaps it, in row order, padded with the lowest
+    rows that do not (engine buckets can exceed R). Returns
+    (rows, rows_ok, truncated, n_overlap)."""
     overlap = _boxes_overlap(view.bbox_lo, view.bbox_hi, lo[None, :],
                              hi[None, :]) & view.active
-    R = overlap.shape[0]
     n_overlap = jnp.sum(overlap, dtype=jnp.int32)
-    # top_k on the negated selection key picks the same rows, in the
-    # same order, as the old full `argsort(key)[:max_rows]` over R —
-    # overlapping rows keep key -row (so descending top_k yields row
-    # order), the rest collapse to -R and tie-break by lowest index,
-    # exactly like the stable argsort — without sorting all R rows.
-    # Engine buckets can exceed R; the slice semantics cap at R.
-    key = jnp.where(overlap, -jnp.arange(R, dtype=jnp.int32),
-                    jnp.int32(-R))
-    _, rows = jax.lax.top_k(key, min(int(max_rows), R))
-    rows = rows.astype(jnp.int32)
-    rows_ok = overlap[rows]
-    truncated = n_overlap > max_rows
-    return rows, rows_ok, truncated
+    rows = _first_true(overlap, max_rows)
+    rows_ok = jnp.arange(rows.shape[0], dtype=jnp.int32) < n_overlap
+    return rows, rows_ok, n_overlap > max_rows, n_overlap
 
 
 def _range_count_single(view: LeafView, lo, hi, max_rows: int):
-    rows, rows_ok, truncated = _range_rows(view, lo, hi, max_rows)
+    rows, rows_ok, _, n_overlap = _range_rows(view, lo, hi, max_rows)
     pts = view.pts[rows]
     inside = (jnp.all((_f32(pts) >= _f32(lo)) & (_f32(pts) <= _f32(hi)),
                       axis=-1)
               & view.valid[rows] & rows_ok[:, None])
-    return jnp.sum(inside, dtype=jnp.int32), truncated
+    return jnp.sum(inside, dtype=jnp.int32), n_overlap
 
 
 def range_count_impl(view: LeafView, lo, hi, max_rows: int = 128):
-    """Unjitted :func:`range_count` — use inside shard_map/pjit regions."""
+    """Unjitted range count — use inside shard_map/pjit regions.
+
+    Returns (counts (Q,), n_overlap (Q,)): each box's count of rows
+    whose bbox overlaps it; the count is exact where n_overlap <=
+    max_rows."""
     return jax.vmap(lambda l, h: _range_count_single(view, l, h, max_rows))(
         lo, hi)
 
@@ -168,12 +207,13 @@ def range_count(view: LeafView, lo, hi, max_rows: int = 128):
 
     Returns (counts (Q,), truncated (Q,)); a True truncated flag means
     max_rows was too small for exactness (resize and re-run)."""
-    return range_count_impl(view, lo, hi, max_rows)
+    cnt, n_overlap = range_count_impl(view, lo, hi, max_rows)
+    return cnt, n_overlap > max_rows
 
 
 def _range_list_single(view: LeafView, lo, hi, max_rows: int, cap: int):
     R, C, dim = view.pts.shape
-    rows, rows_ok, truncated = _range_rows(view, lo, hi, max_rows)
+    rows, rows_ok, truncated, _ = _range_rows(view, lo, hi, max_rows)
     pts = view.pts[rows]
     inside = (jnp.all((_f32(pts) >= _f32(lo)) & (_f32(pts) <= _f32(hi)),
                       axis=-1)
@@ -182,11 +222,9 @@ def _range_list_single(view: LeafView, lo, hi, max_rows: int, cap: int):
     flat_ids = (rows[:, None] * C
                 + jnp.arange(C, dtype=jnp.int32)[None, :]).reshape(-1)
     # stable compaction of hits to the front
-    key = jnp.where(flat_in, jnp.arange(flat_in.shape[0], dtype=jnp.int32),
-                    jnp.int32(flat_in.shape[0]))
-    sel = jnp.argsort(key)[:cap]
-    ids = jnp.where(flat_in[sel], flat_ids[sel], -1)
     count = jnp.sum(flat_in, dtype=jnp.int32)
+    sel = _first_true(flat_in, cap)
+    ids = jnp.where(jnp.arange(sel.shape[0]) < count, flat_ids[sel], -1)
     return ids, count, truncated
 
 

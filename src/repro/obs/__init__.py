@@ -54,6 +54,9 @@ Instrumented out of the box (counter/span names are stable API):
                                       per-pair capacity
 ``engine.range_count.sync`` span      the truncation read of each round
                                       (a host wait on the device)
+``engine.range_count.overlap_rows``   largest count of rows one box
+                                      overlaps (one shard's on a mesh),
+                                      from that read: one a round
 ``engine.range_list.sync`` span       range-list's truncation and
                                       count reads of each round
 ``index.update_plan_miss``            update-closure compiles

@@ -44,7 +44,8 @@ for i in range(24):
 
 # exact range count
 lo, hi = gen.query_boxes(jax.random.PRNGKey(3), 8, 2, gen.DEFAULT_HI // 8)
-cnt, trunc = D.range_count(idx, lo, hi, mesh, max_rows=2048)
+cnt, overlap = D.range_count(idx, lo, hi, mesh, max_rows=2048)
+assert int(overlap) <= 2048, int(overlap)
 for i in range(8):
     bf = int(jnp.sum(jnp.all((allp >= lo[i]) & (allp <= hi[i]), -1)))
     assert int(cnt[i]) == bf, (i, int(cnt[i]), bf)

@@ -178,6 +178,27 @@ def test_escalation_counter_matches_rounds_histogram():
     assert rec.counters.get("engine.escalation", 0) == int(h.total)
 
 
+def test_range_overlap_rows_observed_once_per_round():
+    """``engine.range_count.overlap_rows`` records, from the round's
+    one truncation read, the largest count of rows one box overlaps:
+    one observation for a call that converges at once, equal to a brute
+    force over the view's row boxes."""
+    pts = _pts(256, seed=6)
+    idx = make_index("porth", pts)
+    lo = np.asarray([[10.0, 10.0], [0.0, 50.0], [90.0, 0.0]], np.float32)
+    hi = lo + np.float32(30.0)
+    view = idx.view()
+    blo, bhi = np.asarray(view.bbox_lo), np.asarray(view.bbox_hi)
+    hit = ((blo[None] <= hi[:, None]) & (lo[:, None] <= bhi[None])).all(-1)
+    want = int((hit & np.asarray(view.active)).sum(-1).max())
+    with obs.recording() as rec:
+        idx.range_count(lo, hi)
+    h = rec.hist("engine.range_count.overlap_rows")
+    assert rec.hist("engine.escalation_rounds").samples == [0.0]
+    assert h is not None and h.samples == [float(want)]
+    assert 0 < want < view.active.shape[0]
+
+
 # -- batcher counters -------------------------------------------------------
 
 def test_batcher_coalesce_pad_and_flush_reasons():

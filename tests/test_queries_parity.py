@@ -22,6 +22,9 @@ orders of magnitude.
 
 from __future__ import annotations
 
+import json
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -198,28 +201,83 @@ def test_knn_compensated_parity_outside_f32_window(kind):
 
 
 # ---------------------------------------------------------------------------
-# _range_rows: top_k candidate selection == old argsort (regression)
+# _range_rows: blocked prefix-sum selection == argsort reference
 # ---------------------------------------------------------------------------
 
-def test_range_rows_topk_matches_argsort_reference():
-    """`_range_rows` now selects candidate rows with `lax.top_k` on a
-    negated key; it must reproduce the old full-argsort spelling bit
-    for bit (same rows, same order, same flags) at every bucket size,
-    including buckets past R."""
-    rng = np.random.default_rng(5)
-    pts = rng.integers(0, 1 << 20, size=(3000, 2)).astype(np.int32)
-    idx = make_index("spac-h", jnp.asarray(pts), phi=PHI)
-    view = idx.view()
-    R = view.pts.shape[0]
-    for t in range(10):
-        lo = jnp.asarray(rng.integers(0, 1 << 19, 2), jnp.int32)
-        hi = lo + jnp.asarray(rng.integers(1, 1 << 19, 2), jnp.int32)
+def _mask_view(overlap, active):
+    """A leaf view whose row r overlaps the box (1, 0)-(1, 0) exactly
+    where ``overlap[r]``: a point-like bbox at x = 1, else at x = 0."""
+    R = overlap.shape[0]
+    box = np.stack([overlap.astype(np.int32), np.zeros(R, np.int32)], -1)
+    return queries.LeafView(
+        pts=jnp.zeros((R, 2, 2), jnp.int32),
+        valid=jnp.zeros((R, 2), bool), active=jnp.asarray(active),
+        bbox_lo=jnp.asarray(box), bbox_hi=jnp.asarray(box))
+
+
+def _spread(R, n, seed):
+    m = np.zeros(R, bool)
+    m[np.random.default_rng(seed).choice(R, n, replace=False)] = True
+    return m
+
+
+def _seeded(R, density, seed):
+    return np.random.default_rng(seed).random(R) < density
+
+
+B = queries.SELECT_BLOCK
+# (overlap, active, max_rows values): each case's masks, by name
+_ROW_CASES = {
+    "seeded-sparse": (_seeded(3001, 0.02, 1), np.ones(3001, bool),
+                      (4, 64, 128)),
+    "seeded-half": (_seeded(2000, 0.5, 2), _seeded(2000, 0.9, 3),
+                    (1, 128, 1024, 2000)),
+    "seeded-dense": (_seeded(1500, 0.97, 4), np.ones(1500, bool),
+                     (256, 1500, 4096)),
+    "no-overlap": (np.zeros(1000, bool), np.ones(1000, bool), (1, 128)),
+    "exactly-max-rows": (_spread(4000, 128, 5), np.ones(4000, bool),
+                         (128,)),
+    "more-than-max-rows": (_spread(4000, 300, 6), np.ones(4000, bool),
+                           (128, 256)),
+    "max-rows-past-R": (_seeded(77, 0.4, 7), np.ones(77, bool),
+                        (128, 256)),
+    "R-not-block-multiple": (np.arange(32 * B + 3) >= 32 * B - 5,
+                             np.ones(32 * B + 3, bool), (4, 8, 16)),
+    "inactive-inside-box": (_spread(2000, 400, 8),
+                            np.arange(2000) % 3 != 0, (64, 512)),
+}
+
+
+@pytest.mark.parametrize("case", ["spac-h", *_ROW_CASES])
+def test_range_rows_topk_matches_argsort_reference(case):
+    """`_range_rows` selects candidate rows with a blocked prefix sum;
+    it must reproduce the full stable-argsort spelling bit for bit
+    (same rows, same order, same padding, same flags) at every bucket
+    size, including buckets past R: on a spac-h index's rows for random
+    boxes, and on seeded and edge-case overlap masks."""
+    if case == "spac-h":
+        rng = np.random.default_rng(5)
+        pts = rng.integers(0, 1 << 20, size=(3000, 2)).astype(np.int32)
+        view = make_index("spac-h", jnp.asarray(pts), phi=PHI).view()
+        R = view.pts.shape[0]
+        boxes = []
+        for _ in range(10):
+            lo = jnp.asarray(rng.integers(0, 1 << 19, 2), jnp.int32)
+            hi = lo + jnp.asarray(rng.integers(1, 1 << 19, 2), jnp.int32)
+            boxes.append((lo, hi))
+        buckets = (4, 128, R, 2 * R)
+    else:
+        mask, active, buckets = _ROW_CASES[case]
+        view = _mask_view(mask, active)
+        boxes = [(jnp.asarray([1, 0], jnp.int32),) * 2]
+        R = mask.shape[0]
+    for lo, hi in boxes:
         overlap = np.asarray(
             queries._boxes_overlap(view.bbox_lo, view.bbox_hi,
                                    lo[None, :], hi[None, :])
             & view.active)
-        for max_rows in (4, 128, R, 2 * R):
-            rows, rows_ok, trunc = queries._range_rows(
+        for max_rows in buckets:
+            rows, rows_ok, trunc, n_overlap = queries._range_rows(
                 view, lo, hi, max_rows)
             key = np.where(overlap, np.arange(R), R)
             want = np.argsort(key, kind="stable")[:max_rows]
@@ -227,6 +285,39 @@ def test_range_rows_topk_matches_argsort_reference():
             np.testing.assert_array_equal(np.asarray(rows_ok),
                                           overlap[want])
             assert bool(trunc) == (int(overlap.sum()) > max_rows)
+            assert int(n_overlap) == int(overlap.sum())
+
+
+_HLO_INSTR = re.compile(
+    r"\s*(?:ROOT )?(\S+) = (\(.*?\)|\S+) ([a-z][\w-]*)\(([^)]*)\)")
+
+
+def test_range_count_lowers_without_row_sort_or_scatter():
+    """Lowering guard: no sort, top-k (a sort on the TPU) or scatter in
+    range count's program takes an operand with the tree's R = 4,099
+    rows (not a multiple of the selection block) in its shape, so the
+    sort over all rows per query cannot come back unnoticed on a
+    CPU-only run."""
+    R, C = 4099, 8
+    view = queries.LeafView(
+        pts=jnp.zeros((R, C, 2), jnp.int32), valid=jnp.zeros((R, C), bool),
+        active=jnp.zeros((R,), bool), bbox_lo=jnp.zeros((R, 2), jnp.int32),
+        bbox_hi=jnp.zeros((R, 2), jnp.int32))
+    lo = jnp.zeros((16, 2), jnp.int32)
+    hlo = queries.range_count.lower(view, lo, lo, 128).as_text(
+        dialect="hlo")
+    shapes = {}
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op, args = m.groups()
+        shapes[name] = shape
+        if op in ("sort", "topk", "scatter"):
+            for arg in args.split(","):
+                dims = re.findall(r"\d+", shapes[arg.strip()].split("{")[0])
+                assert str(R) not in dims, line
+    assert len(shapes) > 50     # the parse saw the program
 
 
 # ---------------------------------------------------------------------------
@@ -442,3 +533,46 @@ def test_distributed_engine_queries():
     # full distributed query path (see tests/helpers.py)
     run_on_simulated_mesh(_DIST_SCRIPT, 8, timeout_base_s=900,
                           expect="DIST_ENGINE_OK")
+
+
+_DIST_RANGE_SCRIPT = r"""
+import json
+import jax.numpy as jnp, numpy as np
+from repro.core import distributed as D, make_index
+
+rng = np.random.default_rng(31)
+pts = rng.integers(0, 1 << 20, size=(6000, 2)).astype(np.int32)
+lo = rng.integers(0, 1 << 20, size=(16, 2)).astype(np.int32)
+side = rng.integers(1, 1 << 18, size=(16, 1)).astype(np.int32)
+lo[0], side[0] = 0, (1 << 20) - 1          # every point, every row
+lo[1] = (1 << 20) - 1                       # a corner box
+idx = make_index("spac-h", jnp.asarray(pts), mesh=mesh, phi=8)
+idx.engine.start_rows = 8                   # escalates on shard rows
+cnt = idx.range_count(jnp.asarray(lo), jnp.asarray(lo + side))
+# the raw exchange at a bucket too small for box 0 still returns the
+# exact largest count of one shard's rows overlapping one box
+_, overlap = D.range_count(idx._index, jnp.asarray(lo),
+                           jnp.asarray(lo + side), idx.mesh, max_rows=8)
+t = idx._index.tree
+blo, bhi = np.asarray(t.bbox_lo), np.asarray(t.bbox_hi)
+hit = ((blo[None] <= (lo + side)[:, None, None])
+       & (lo[:, None, None] <= bhi[None])).all(-1) & np.asarray(t.active)
+assert int(overlap) == int(hit.sum(-1).max()), int(overlap)
+print("COUNTS", json.dumps({"pts": pts.tolist(), "lo": lo.tolist(),
+                            "hi": (lo + side).tolist(),
+                            "cnt": np.asarray(cnt).tolist()}))
+"""
+
+
+def test_distributed_range_count_matches_oracle():
+    """Distributed range count over a 4-device CPU mesh equals the
+    numpy oracle, the whole-domain box included, from a starting bucket
+    small enough that the engine escalates across the shards' rows; the
+    exchange's largest overlap equals a brute force over the shards'
+    row boxes."""
+    out = run_on_simulated_mesh(_DIST_RANGE_SCRIPT, 4, timeout_base_s=900,
+                                expect="COUNTS")
+    got = json.loads(out.stdout.split("COUNTS", 1)[1])
+    pts, lo, hi = (np.asarray(got[k], np.int32) for k in ("pts", "lo", "hi"))
+    np.testing.assert_array_equal(np.asarray(got["cnt"], np.int64),
+                                  oracle_range_count(pts, lo, hi))
